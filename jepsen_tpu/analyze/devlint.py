@@ -245,12 +245,9 @@ def walk_jaxpr(jaxpr, path=()):
 def _eqn_line(eqn) -> tuple[str, int] | None:
     """(filename, lineno) of the user frame that staged this equation,
     when jax kept one — the anchor for ``devlint: ok`` suppression."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        fr = source_info_util.user_frame(eqn.source_info)
-    except Exception:  # pragma: no cover — internal API moved
-        return None
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
     if fr is None:
         return None
     line = getattr(fr, "start_line", None) or getattr(fr, "line_num", 0)

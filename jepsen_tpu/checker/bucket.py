@@ -17,11 +17,9 @@ work instead of one ragged megabatch.
 * **Kernel memoization** — buckets reuse compiled kernels per (model,
   dims, bucket-size-class) through the ordinary kernel cache
   (`get_batch_kernel`; hit/miss counters in `KERNEL_CACHE_STATS`), so
-  a steady stream of same-shaped buckets never retraces.  Point
-  ``jax_compilation_cache_dir`` at a persistent path (the
-  JEPSEN_TPU_COMPILE_CACHE_DIR knob, the CLI's --compile-cache-dir,
-  or bench.py's .jax_cache default) and compiles survive processes
-  too.
+  a steady stream of same-shaped buckets never retraces; with JAX's
+  persistent cache on (util.enable_compilation_cache, which every
+  entry point calls) compiles survive processes too.
 * **Pipelining** — while bucket k executes on device (the ladder
   blocks inside XLA executions, which release the GIL), a prep thread
   greedy-witnesses and tight-pads bucket k+1, so that host
@@ -485,6 +483,7 @@ def search_batch_sharded_bucketed(seqs: list[OpSeq], model: ModelSpec,
     useful_total = padded_total = 0
     pad_lanes_total = redo_total = 0
     shard_map_all = True
+    device_keys: dict = {}  # device id -> real keys placed there
     run_all: list[int] = []
     if plans:
         ex = ThreadPoolExecutor(max_workers=1,
@@ -537,6 +536,8 @@ def search_batch_sharded_bucketed(seqs: list[OpSeq], model: ModelSpec,
                     pad_lanes_total += info["pad_lanes"]
                     redo_total += info["overflow_redo"]
                     shard_map_all &= info["shard_map"]
+                    for d, k in info["device_keys"].items():
+                        device_keys[d] = device_keys.get(d, 0) + k
                 run_all += run
                 stats["buckets"].append({
                     "dims": ([dims.n_det_pad, dims.window,
@@ -584,6 +585,7 @@ def search_batch_sharded_bucketed(seqs: list[OpSeq], model: ModelSpec,
         "pad_keys": pad_lanes_total,
         "overflow_redo": redo_total,
         "shard_map": shard_map_all if run_all else None,
+        "device_keys": device_keys,
         "padding_efficiency": (round(useful_total / padded_total, 4)
                                if padded_total else None),
         "fused_padded_ops": fused_padded or None,

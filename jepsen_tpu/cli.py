@@ -150,12 +150,13 @@ def add_test_opts(p: argparse.ArgumentParser) -> None:
                         "Sets JEPSEN_TPU_AUDIT=1 fleet-wide so every "
                         "suite-constructed checker honors it.")
     p.add_argument("--compile-cache-dir", metavar="DIR", default=None,
-                   help="Persistent JAX compilation-cache directory "
-                        "(jax_compilation_cache_dir): compiled search "
-                        "kernels survive across processes, so repeat "
-                        "runs and the bucketed batch scheduler's "
-                        "steady-state buckets never retrace.  Also "
-                        "honored from JEPSEN_TPU_COMPILE_CACHE_DIR.")
+                   help="Persistent JAX compilation-cache directory: "
+                        "compiled search kernels survive across "
+                        "processes, so repeat runs and the bucketed "
+                        "batch scheduler's steady-state buckets never "
+                        "recompile.  Sets JAX_COMPILATION_CACHE_DIR; "
+                        "without either, the cache is "
+                        "<repo>/.jax_cache.")
 
 
 def add_tarball_opt(p: argparse.ArgumentParser, default: str | None = None,
@@ -262,13 +263,12 @@ def test_opt_fn(parsed: argparse.Namespace) -> dict:
         opts["audit"] = True
     ccd = opts.get("compile_cache_dir")
     if ccd:
-        # the env var carries the setting into spawned workers/children;
-        # the config update applies it to THIS process (deferred import:
-        # the CLI must not pay backend init for --help)
-        os.environ["JEPSEN_TPU_COMPILE_CACHE_DIR"] = ccd
-        from .util import enable_compilation_cache
+        # --compile-cache-dir is JAX_COMPILATION_CACHE_DIR spelled as a
+        # flag: the env var carries it into spawned workers/children
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = ccd
+    from .util import enable_compilation_cache
 
-        enable_compilation_cache(ccd)
+    opts["compile_cache_dir"] = enable_compilation_cache()
     return opts
 
 

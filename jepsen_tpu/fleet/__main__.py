@@ -10,6 +10,11 @@ ring — clients notice only that shedding stops.
 
 SIGTERM drains the tier: workers get SIGTERM (their graceful-drain
 handler finalizes open runs and exits 0), then the router stops.
+
+One chip per worker: a chip belongs to one process at a time, so on a
+TPU host each worker is given its own chip through its environment
+(:func:`worker_env`) and the fleet refuses more workers than chips.
+The supervisor itself never touches JAX.
 """
 
 from __future__ import annotations
@@ -27,15 +32,49 @@ log = logging.getLogger("jepsen_tpu.fleet")
 _LISTEN_MARK = "stream service listening on "
 _WARMUP_MARK = "stream service warmup:"
 
+#: first of the per-worker TPU runtime ports (two per chip)
+_TPU_PORT0 = 8476
+
+
+def tpu_chips() -> int:
+    """Chips attached to this host, counted from their device nodes —
+    no JAX, so the supervisor never claims one (0 off a TPU host)."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*"))
+
+
+def worker_env(chip: int | None, base: dict | None = None) -> dict:
+    """The environment of the worker that owns ``chip``: the TPU
+    runtime sees that one chip as a one-chip host of its own, with
+    ports no other worker uses.  ``None`` (no TPU host) passes the
+    environment through unchanged."""
+    env = dict(os.environ if base is None else base)
+    if chip is None:
+        return env
+    port = _TPU_PORT0 + 2 * chip
+    env.update({
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(port),
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port + 1}",
+        "TPU_MESH_CONTROLLER_PORT": str(port + 1),
+    })
+    return env
+
 
 class WorkerProc:
     """One supervised worker subprocess + its parsed boot lines."""
 
-    def __init__(self, wid: str, args, cmd: list[str]):
+    def __init__(self, wid: str, args, cmd: list[str], *,
+                 chip: int | None = None):
         self.wid = wid
+        self.chip = chip
         self.proc = subprocess.Popen(
             cmd, stderr=subprocess.PIPE, stdout=subprocess.DEVNULL,
-            text=True)
+            text=True, env=worker_env(chip))
         self.address: tuple[str, int] | None = None
         self.warmup: dict | None = None
         self._boot(timeout=args.boot_timeout)
@@ -103,6 +142,11 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     args.boot_timeout = 120.0
     logging.basicConfig(level=logging.INFO)
+    chips = tpu_chips()
+    if chips and args.workers > chips:
+        log.error("fleet: %d workers asked for but this host has %d "
+                  "TPU chips (one chip per worker)", args.workers, chips)
+        return 2
 
     from .. import store
     from .admission import AdmissionController
@@ -113,7 +157,8 @@ def main(argv=None) -> int:
     persist = args.persist_dir or os.path.join(cache_root, "persist")
     os.makedirs(persist, exist_ok=True)
 
-    state = {"n": 0, "procs": {}}
+    state = {"n": 0, "procs": {},
+             "free_chips": list(range(chips)) if chips else None}
     lock = threading.Lock()
 
     def worker_cmd(wid: str) -> list[str]:
@@ -132,24 +177,39 @@ def main(argv=None) -> int:
             cmd += ["--idle-timeout", str(args.idle_timeout)]
         return cmd
 
+    def release(chip: int | None) -> None:
+        if chip is not None:
+            with lock:
+                state["free_chips"].append(chip)
+
     def spawn_worker() -> bool:
         with lock:
             if len(state["procs"]) >= args.max_workers:
                 log.info("fleet: at max-workers=%d, not spawning",
                          args.max_workers)
                 return False
+            chip = None
+            if state["free_chips"] is not None:
+                if not state["free_chips"]:
+                    log.info("fleet: every TPU chip has a worker, not "
+                             "spawning")
+                    return False
+                chip = state["free_chips"].pop(0)
             state["n"] += 1
             wid = f"w{state['n']}"
-        log.info("fleet: spawning worker %s", wid)
+        log.info("fleet: spawning worker %s (chip %s)", wid, chip)
         try:
-            wp = WorkerProc(wid, args, worker_cmd(wid))
+            wp = WorkerProc(wid, args, worker_cmd(wid), chip=chip)
         except RuntimeError:
             log.warning("fleet: worker %s failed to boot", wid,
                         exc_info=True)
+            release(chip)
             return False
         spec = WorkerSpec(wid, wp.address[0], wp.address[1], persist)
         if not router.admit_worker(spec, warmup_report=wp.warmup):
             wp.proc.terminate()
+            wp.proc.wait()
+            release(chip)
             return False
         with lock:
             state["procs"][wid] = wp

@@ -83,8 +83,10 @@ def _register_pystep(state, f, v1, v2):
 
 def _register_jstep(state, f, v1, v2):
     val = state[0]
-    is_read = f == R_READ
-    legal = jnp.where(is_read, (v1 == NIL) | (v1 == val), True)
+    # legality as boolean algebra, not a select of booleans: the same
+    # step runs inside the Pallas level kernel, and Mosaic cannot
+    # lower a select whose operands are i1 vectors
+    legal = (f != R_READ) | (v1 == NIL) | (v1 == val)
     new_val = jnp.where(f == R_WRITE, v1, val)
     return jnp.stack([new_val]), legal
 
@@ -124,8 +126,9 @@ def _cas_register_jstep(state, f, v1, v2):
     val = state[0]
     read_legal = (v1 == NIL) | (v1 == val)
     cas_legal = v1 == val
-    legal = jnp.where(f == R_READ, read_legal,
-                      jnp.where(f == R_CAS, cas_legal, True))
+    # boolean algebra, not a select of booleans (see _register_jstep)
+    legal = (((f == R_READ) & read_legal) | ((f == R_CAS) & cas_legal)
+             | ((f != R_READ) & (f != R_CAS)))
     new_val = jnp.where(f == R_WRITE, v1,
                         jnp.where((f == R_CAS) & cas_legal, v2, val))
     return jnp.stack([new_val]), legal
@@ -168,7 +171,9 @@ def _mutex_pystep(state, f, v1, v2):
 
 def _mutex_jstep(state, f, v1, v2):
     locked = state[0]
-    legal = jnp.where(f == M_ACQUIRE, locked == 0, locked == 1)
+    # boolean algebra, not a select of booleans (see _register_jstep)
+    legal = (((f == M_ACQUIRE) & (locked == 0))
+             | ((f != M_ACQUIRE) & (locked == 1)))
     new_locked = jnp.where(f == M_ACQUIRE, 1, 0)
     return jnp.stack([jnp.where(legal, new_locked, locked)]), legal
 

@@ -303,7 +303,7 @@ REGISTRY = Registry()
 
 def _declare(reg: Registry) -> None:
     """Declare the standing metric set so a fresh scrape shows the
-    whole taxonomy (zeros included for the unlabelled ones) instead of
+    whole catalog (zeros included for the unlabelled ones) instead of
     only what has fired.  Modules re-obtain these handles by name."""
     reg.counter("jtpu_ops_total",
                 "Client worker op completions by type",

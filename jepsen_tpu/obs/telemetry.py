@@ -475,19 +475,15 @@ def transfer_bytes(arrays) -> int:
 
 
 def persistent_cache_configured() -> bool:
-    """Whether a persistent XLA compile cache is configured — via the
-    ``JEPSEN_TPU_COMPILE_CACHE_DIR`` env or jax's own
-    ``jax_compilation_cache_dir`` knob.  Compile spans record it per
-    miss and the fleet warm-boot gate (fleet/warmup.py) reports it per
-    worker, so cold-start compile tax is attributable either way."""
-    if os.environ.get("JEPSEN_TPU_COMPILE_CACHE_DIR"):
-        return True
-    try:
-        import jax
+    """Whether a persistent XLA compile cache is configured (jax's
+    ``jax_compilation_cache_dir``, which ``JAX_COMPILATION_CACHE_DIR``
+    and util.enable_compilation_cache both set).  Compile spans record
+    it per miss and the fleet warm-boot gate (fleet/warmup.py) reports
+    it per worker, so cold-start compile tax is attributable either
+    way."""
+    import jax
 
-        return bool(jax.config.jax_compilation_cache_dir)
-    except Exception:  # noqa: BLE001 — old jax without the knob
-        return False
+    return bool(jax.config.jax_compilation_cache_dir)
 
 
 def compile_span(**attrs):

@@ -112,6 +112,10 @@ def main(argv=None) -> int:
             path = default_cache_path()
         cache = VerdictCache(path)
 
+    # device folds compile search kernels: keep them across restarts
+    from ..util import enable_compilation_cache
+
+    enable_compilation_cache()
     if args.warmup:
         # ahead-of-time kernel warmup BEFORE the listen line prints:
         # the fleet admission gate must not route traffic at a worker

@@ -286,6 +286,10 @@ class StreamChecker:
 
         self._q: _queue.Queue | None = None
         self._worker: threading.Thread | None = None
+        #: the first exception a fold raised on the fold thread; it is
+        #: re-raised where the folds are joined, as a synchronous fold
+        #: would have raised it to the ingest caller
+        self._fold_error: BaseException | None = None
         if async_folds:
             self._q = _queue.Queue()
             self._worker = threading.Thread(target=self._worker_loop,
@@ -511,13 +515,13 @@ class StreamChecker:
                               exc_info=True)
                 self._maybe_write_live()
                 continue
+            if self._fold_error is not None:
+                continue  # the run is failing; drain the queue
             try:
                 self._fold(cell, rows)
-            except Exception:  # noqa: BLE001 — one segment, not the run
-                log.warning("stream: segment fold crashed; falling back",
-                            exc_info=True)
-                cell.fallback = True
-                self._fallback = True
+            except Exception as e:  # noqa: BLE001 — raised at the join
+                self._fold_error = e
+                continue
             self._maybe_write_live()
 
     def _fold(self, cell: _Cell, retained: list[_Row]) -> None:
@@ -858,6 +862,8 @@ class StreamChecker:
                 self._worker.join()
             self._q = None
             self._worker = None
+        if self._fold_error is not None:
+            raise self._fold_error
 
     def finalize(self, *, audit: bool | None = None) -> dict:
         """Close the stream and emit the final result dict (same shape
@@ -1177,8 +1183,13 @@ class StreamChecker:
         return sub
 
     def close(self) -> None:
-        """Stop the fold worker without finalizing (abandoned stream)."""
-        self._drain_folds()
+        """Stop the fold worker without finalizing (abandoned stream:
+        a fold error is finalize's to raise, not the abandon path's)."""
+        err, self._fold_error = self._fold_error, None
+        try:
+            self._drain_folds()
+        finally:
+            self._fold_error = err
 
 
 class TotalFoldStream:

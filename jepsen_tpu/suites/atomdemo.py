@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import random
 import threading
+import time
 from dataclasses import replace
 
 from .. import (checker as checker_mod, cli, client as client_mod,
@@ -24,9 +25,13 @@ from ..models import cas_register
 class AtomMapClient(client_mod.Client):
     """Per-key CAS registers over a shared dict of AtomRegisters."""
 
-    def __init__(self, registers=None, lock=None):
+    def __init__(self, registers=None, lock=None, ack_latency=0.0):
         self.registers = registers if registers is not None else {}
         self.lock = lock or threading.Lock()
+        #: upper bound of a random delay between applying an op and
+        #: acknowledging it, as a network hop would add: completions
+        #: then reach the history out of linearization order
+        self.ack_latency = ack_latency
 
     def open(self, test, node):
         return self
@@ -39,15 +44,19 @@ class AtomMapClient(client_mod.Client):
         k, v = op.value.key, op.value.value
         reg = self._reg(k)
         if op.f == "read":
-            return replace(op, type="ok",
-                           value=independent.tuple_(k, reg.read()))
-        if op.f == "write":
+            out = replace(op, type="ok",
+                          value=independent.tuple_(k, reg.read()))
+        elif op.f == "write":
             reg.write(v)
-            return replace(op, type="ok")
-        if op.f == "cas":
+            out = replace(op, type="ok")
+        elif op.f == "cas":
             old, new = v
-            return replace(op, type="ok" if reg.cas(old, new) else "fail")
-        raise ValueError(f"unknown f {op.f!r}")
+            out = replace(op, type="ok" if reg.cas(old, new) else "fail")
+        else:
+            raise ValueError(f"unknown f {op.f!r}")
+        if self.ack_latency:
+            time.sleep(random.uniform(0.0, self.ack_latency))
+        return out
 
 
 def r(test, process):
@@ -78,7 +87,8 @@ def atom_test(opts: dict) -> dict:
     return fixtures.noop_test() | dict(opts) | {
         "name": "atomdemo",
         "concurrency": max(group, conc),
-        "client": AtomMapClient(),
+        "client": AtomMapClient(
+            ack_latency=opts.get("ack_latency", 0.0)),
         "nemesis": nemesis.noop,
         "model": cas_register(0),
         "checker": checker_mod.compose({
@@ -103,6 +113,11 @@ def add_opts(p):
     p.add_argument("-r", "--rate", type=float, default=50)
     p.add_argument("--ops-per-key", type=int, default=50)
     p.add_argument("--group-size", type=int, default=2)
+    p.add_argument("--ack-latency", type=float, default=0.0,
+                   metavar="SECONDS",
+                   help="Upper bound of a random delay before each "
+                        "acknowledgement, so completions reach the "
+                        "history out of linearization order.")
 
 
 def main(argv=None):

@@ -329,22 +329,61 @@ def test_portfolio_races_decomposed_leg():
 
 
 def test_enable_compilation_cache(tmp_path, monkeypatch):
+    """One placement rule: JAX_COMPILATION_CACHE_DIR where it is set
+    (and no other path), else the fixed <repo>/.jax_cache."""
     import jax
 
-    from jepsen_tpu.util import enable_compilation_cache
+    from jepsen_tpu.util import enable_compilation_cache, repo_root
 
     old = jax.config.jax_compilation_cache_dir
     try:
-        assert enable_compilation_cache(str(tmp_path)) == str(tmp_path)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compilation_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-        # env fallback
-        monkeypatch.setenv("JEPSEN_TPU_COMPILE_CACHE_DIR",
-                           str(tmp_path / "env"))
-        assert enable_compilation_cache() == str(tmp_path / "env")
-        monkeypatch.delenv("JEPSEN_TPU_COMPILE_CACHE_DIR")
-        assert enable_compilation_cache() is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(repo_root(), ".jax_cache")
+        assert enable_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
     finally:
         jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_compilation_cache_entries_land_in_the_env_dir(tmp_path,
+                                                       monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, a compile writes its entry
+    there, and a fresh compile of the same program reads it back."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from jepsen_tpu.util import enable_compilation_cache
+
+    cfg = jax.config
+    old = (cfg.jax_compilation_cache_dir,
+           cfg.jax_enable_compilation_cache,
+           cfg.jax_persistent_cache_min_compile_time_secs)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        enable_compilation_cache()
+        cfg.update("jax_enable_compilation_cache", True)
+        cfg.update("jax_persistent_cache_min_compile_time_secs", 0)
+        compilation_cache.reset_cache()
+
+        def f(x):
+            return (x * 3 + 1).sum()
+
+        x = jnp.arange(7, dtype=jnp.int32)
+        jax.jit(f).lower(x).compile()
+        entries = set(os.listdir(tmp_path))
+        assert entries, "no cache entry written"
+        jax.clear_caches()
+        jax.jit(f).lower(x).compile()
+        assert set(os.listdir(tmp_path)) == entries  # read, not rewritten
+    finally:
+        cfg.update("jax_compilation_cache_dir", old[0])
+        cfg.update("jax_enable_compilation_cache", old[1])
+        cfg.update("jax_persistent_cache_min_compile_time_secs", old[2])
+        compilation_cache.reset_cache()
 
 
 def test_cli_compile_cache_flag(tmp_path, monkeypatch):
@@ -353,20 +392,23 @@ def test_cli_compile_cache_flag(tmp_path, monkeypatch):
     import jax
 
     from jepsen_tpu import cli
+    from jepsen_tpu.util import repo_root
 
     # the cli sets the env var OUTSIDE monkeypatch; register it so
     # teardown removes it (same trick as test_cli_flag_sets_env_knob)
-    monkeypatch.setenv("JEPSEN_TPU_COMPILE_CACHE_DIR", "placeholder")
-    monkeypatch.delenv("JEPSEN_TPU_COMPILE_CACHE_DIR")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "placeholder")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     old = jax.config.jax_compilation_cache_dir
     try:
         p = argparse.ArgumentParser()
         cli.add_test_opts(p)
+        opts = cli.test_opt_fn(p.parse_args(["--dummy"]))
+        assert opts["compile_cache_dir"] == os.path.join(
+            repo_root(), ".jax_cache")
         opts = cli.test_opt_fn(p.parse_args(
             ["--compile-cache-dir", str(tmp_path), "--dummy"]))
         assert opts["compile_cache_dir"] == str(tmp_path)
-        assert os.environ["JEPSEN_TPU_COMPILE_CACHE_DIR"] == \
-            str(tmp_path)
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == str(tmp_path)
     finally:
         jax.config.update("jax_compilation_cache_dir", old)

@@ -566,3 +566,39 @@ def test_trace_shapes_carry_model_and_shard_coords(tmp_path):
     assert s.shards == 8
     assert s.batch == 16  # per-shard lanes x shard count
     assert s.masked and s.dedup
+
+
+def test_worker_env_gives_each_worker_its_own_chip():
+    """One chip per fleet worker, set in the worker's environment: the
+    TPU runtime of worker k sees chip k alone, with runtime ports no
+    other worker uses; off a TPU host the environment passes through."""
+    from jepsen_tpu.fleet.__main__ import worker_env
+
+    base = {"PATH": "/bin", "TPU_VISIBLE_CHIPS": "stale"}
+    envs = [worker_env(c, base) for c in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    for e in envs:
+        assert e["PATH"] == "/bin"
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    ports = [int(e[k]) for e in envs
+             for k in ("TPU_PROCESS_PORT", "TPU_MESH_CONTROLLER_PORT")]
+    assert len(set(ports)) == len(ports)
+    assert all(e["TPU_MESH_CONTROLLER_ADDRESS"]
+               == f"localhost:{e['TPU_MESH_CONTROLLER_PORT']}"
+               for e in envs)
+    assert worker_env(None, base) == base
+    assert base["TPU_VISIBLE_CHIPS"] == "stale"  # the base is not mutated
+
+
+def test_fleet_refuses_more_workers_than_chips(monkeypatch):
+    """On a TPU host the supervisor refuses more workers than chips
+    before it spawns anything (and without touching JAX)."""
+    from jepsen_tpu.fleet import __main__ as fleet_main
+
+    monkeypatch.setattr(fleet_main, "tpu_chips", lambda: 2)
+    spawned = []
+    monkeypatch.setattr(fleet_main, "WorkerProc",
+                        lambda *a, **k: spawned.append(a))
+    assert fleet_main.main(["--workers", "3"]) == 2
+    assert spawned == []

@@ -497,9 +497,9 @@ def test_fifo_rejects_out_of_order_service():
 
 def test_width_floor_backend_policy(monkeypatch):
     """The narrowest rung is backend-dependent: 16 on CPU (narrow
-    valleys are cheap there), 64 on TPU (on-chip per-level cost is
-    flat below F~64 while every rung costs a compile — see
-    docs/tpu/r4/tpubench.jsonl), env-overridable either way."""
+    valleys are cheap there), 64 on TPU (the chip pads narrow shapes to
+    its vector tiles while every rung costs a compile), env-overridable
+    either way."""
     monkeypatch.setattr(lin, "_WIDTH_FLOOR", None)
     monkeypatch.delenv("JEPSEN_TPU_WIDTH_FLOOR", raising=False)
     assert lin._width_floor() == (

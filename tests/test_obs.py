@@ -343,10 +343,10 @@ def test_service_drops_run_recorder_on_finalize(tracing):
     assert "r-drop" not in trace_mod._recorders
 
 
-def test_registry_declares_standing_taxonomy():
+def test_registry_declares_standing_catalog():
     # the acceptance set: cache-hit-ratio inputs, fold/fork, padding
     # efficiency, watchdog — declared up front so a fresh scrape shows
-    # the whole taxonomy
+    # the whole catalog
     text = obs_metrics.render()
     for name in ("jtpu_verdict_cache_total", "jtpu_kernel_cache_total",
                  "jtpu_stream_segments_folded_total",

@@ -409,9 +409,9 @@ def test_compile_span_detects_persistent_cache(tmp_path,
                                                monkeypatch):
     from jepsen_tpu import util
 
-    monkeypatch.delenv("JEPSEN_TPU_COMPILE_CACHE_DIR", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     prior = jax.config.jax_compilation_cache_dir
-    applied = util.enable_compilation_cache(str(tmp_path))
+    applied = util.enable_compilation_cache()
     assert applied == str(tmp_path)
     obs.enable(True)
     run = "t-compile-pcache"
@@ -422,7 +422,7 @@ def test_compile_span_detects_persistent_cache(tmp_path,
         span = [s for s in obs.recorder(run).spans()
                 if s["name"] == "device.compile"][0]
         assert span["args"]["persistent_cache"] is True
-        jax.config.update("jax_compilation_cache_dir", prior)
+        jax.config.update("jax_compilation_cache_dir", None)
         with tele.compile_span(engine="xla"):
             pass
         span2 = [s for s in obs.recorder(run).spans()

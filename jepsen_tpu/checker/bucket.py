@@ -47,6 +47,7 @@ nearest larger neighbor first), unset/auto = on, at most 8 buckets.
 
 from __future__ import annotations
 
+import contextvars
 import os
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -268,13 +269,17 @@ def search_batch_bucketed(seqs: list[OpSeq], model: ModelSpec, *,
         ex = ThreadPoolExecutor(max_workers=1,
                                 thread_name_prefix="bucket-prep")
         try:
-            fut = ex.submit(prep, plans[0])
+            # each prep runs in a copy of this context: its spans carry
+            # the caller's check id
+            fut = ex.submit(contextvars.copy_context().run, prep,
+                            plans[0])
             for b, idxs in enumerate(plans):
                 ready, run, dims, esps = fut.result()
                 if b + 1 < len(plans):
                     # bucket b+1's host prep overlaps bucket b's device
                     # execution below
-                    fut = ex.submit(prep, plans[b + 1])
+                    fut = ex.submit(contextvars.copy_context().run,
+                                    prep, plans[b + 1])
                 for i, r in ready.items():
                     results[i] = r
                 n_hb = sum(1 for r in ready.values()
@@ -489,13 +494,15 @@ def search_batch_sharded_bucketed(seqs: list[OpSeq], model: ModelSpec,
         ex = ThreadPoolExecutor(max_workers=1,
                                 thread_name_prefix="shard-prep")
         try:
-            fut = ex.submit(prep, plans[0])
+            fut = ex.submit(contextvars.copy_context().run, prep,
+                            plans[0])
             for b, idxs in enumerate(plans):
                 ready, run, dims, esps, dead_pad = fut.result()
                 if b + 1 < len(plans):
                     # bucket b+1's host prep overlaps bucket b's mesh
                     # execution below
-                    fut = ex.submit(prep, plans[b + 1])
+                    fut = ex.submit(contextvars.copy_context().run,
+                                    prep, plans[b + 1])
                 for i, r in ready.items():
                     results[i] = r
                 n_hb = sum(1 for r in ready.values()

@@ -68,7 +68,6 @@ gather.
 from __future__ import annotations
 
 import os
-import sys
 import threading
 import time
 from dataclasses import dataclass, replace as _dc_replace
@@ -1617,15 +1616,20 @@ def search_opseq_sharded(seq: OpSeq, model: ModelSpec, mesh, *,
         return _tele.emit_decided(
             maybe_audit(seq, model, dict(hbres.decided), audit),
             hbres=hbres)
-    es = encode_search(seq)
+    with obs.span("linearizable.prep", rows=len(seq)):
+        es = encode_search(seq)
     if es.n_det == 0 and es.n_crash == 0:
         return finish({"valid": True, "configs": 0, "max_depth": 0,
                        "engine": "trivial", "linearization": []})
-    if greedy_witness(seq, model):
+    with obs.span("linearizable.witness") as sp:
+        found = greedy_witness(seq, model)
+        lin = greedy_linearization(seq) if found else None
+        sp.set(found=found)
+    if found:
         return finish({"valid": True, "configs": es.n_det,
                        "max_depth": es.n_det,
                        "engine": "greedy-witness",
-                       "linearization": greedy_linearization(seq)})
+                       "linearization": lin})
     if es.window > MAX_WINDOW or es.n_crash > MAX_CRASH:
         from .linear import check_opseq_linear
 
@@ -1703,7 +1707,8 @@ def search_opseq_sharded(seq: OpSeq, model: ModelSpec, mesh, *,
                     t = np.asarray(res[7]).reshape(
                         D, TELE_ROWS, TELE_COLS).sum(axis=0)
                     acc.add_slice(t, t0, time.perf_counter(),
-                                  frontier=dims.frontier)
+                                  frontier=dims.frontier,
+                                  levels=sc(res, 4) - entry(carry))
                 except Exception:  # noqa: BLE001 — non-addressable
                     pass           # multihost shards: totals only
                 res = res[:7]
@@ -1714,6 +1719,16 @@ def search_opseq_sharded(seq: OpSeq, model: ModelSpec, mesh, *,
                     and sc(carry, 3) < budget
                     and not (bail and sc(carry, 5)))
 
+        def entry(carry):
+            return _entry_depth(sc(carry, 6), sc(carry, 3), sc(carry, 4))
+
+        def describe(before, after):
+            return {"count": sc(after, 6), "configs": sc(after, 3),
+                    "depth": sc(after, 4),
+                    "levels_run": sc(after, 4) - entry(before),
+                    "overflow": bool(sc(after, 5)),
+                    "status": sc(after, 2)}
+
         prev = [carry0]
 
         def track(carry):
@@ -1723,7 +1738,8 @@ def search_opseq_sharded(seq: OpSeq, model: ModelSpec, mesh, *,
                 on_slice(carry, dims)
 
         carry = _drive_slices(call, carry0, is_active, on_slice=track,
-                              deadline=deadline, stop=stop)
+                              deadline=deadline, stop=stop,
+                              describe=describe)
         status = sc(carry, 2)
         configs = sc(carry, 3)
         ovf = bool(sc(carry, 5))
@@ -1794,17 +1810,6 @@ _SLICE_LEVELS0 = int(os.environ.get("JEPSEN_TPU_SLICE_LEVELS", "32"))
 _SLICE_TARGET_S = float(os.environ.get("JEPSEN_TPU_SLICE_TARGET_S", "2.0"))
 _SLICE_MAX = 16384
 
-#: per-slice trace lines on stderr (width, cap, wall, live rows, configs,
-#: depth) — with this on, the last trace line of a search that stalls
-#: names the slice it stalled in
-_TRACE_SLICES = os.environ.get("JEPSEN_TPU_TRACE_SLICES", "") not in ("",
-                                                                      "0")
-
-
-def _trace(msg: str) -> None:
-    if _TRACE_SLICES:
-        print(f"slice: {msg}", file=sys.stderr, flush=True)
-
 
 _SLICE_HARD_S: float | None = None
 
@@ -1845,8 +1850,17 @@ def _adapt_lvl_cap(lvl_cap: int, dt: float,
     return lvl_cap
 
 
+def _entry_depth(count, configs, depth):
+    """A carry's depth as the start of the levels the next slice runs
+    (scalars or per-lane arrays): a level searches at the depth of its
+    rows, the root's 0 included, so a carry still to search its root
+    (live, no configuration counted yet) stands at -1."""
+    return depth - ((configs == 0) & (count > 0))
+
+
 def _drive_slices(call, carry, is_active, *, on_slice=None,
-                  deadline: float | None = None, stop=None):
+                  deadline: float | None = None, stop=None,
+                  describe=None):
     """Shared host loop for the batch and sharded kernels.  (The
     single-device path has its own driver inside ``_run_kernel``: it
     re-keys the kernel between slices as the frontier width adapts,
@@ -1858,17 +1872,20 @@ def _drive_slices(call, carry, is_active, *, on_slice=None,
     (perf_counter clock) / ``stop`` (threading.Event) end the drive
     between slices with the carry as-is — still-active carries map to
     an "unknown" verdict in the callers.  The first slice's wall time
-    includes trace+compile, so it never feeds cap adaptation."""
-    from .. import obs
-
+    includes trace+compile, so it never feeds cap adaptation.
+    ``describe(before, after)``, with tracing on, gives the slice's
+    ``device.slice`` args read off its carries (`_slice_args`)."""
     lvl_cap = _SLICE_LEVELS0
     first = True
     while True:
         t0 = time.perf_counter()
         with obs.span("device.slice", cat="device", levels=lvl_cap,
-                      first=first):
+                      first=first) as sp:
+            before = carry
             carry = call(carry, lvl_cap)
             jax.block_until_ready(carry)
+            if describe is not None and obs.enabled():
+                sp.set(**describe(before, carry))
         dt = time.perf_counter() - t0
         _tele.record_device_seconds(dt)
         if on_slice is not None:
@@ -2208,7 +2225,8 @@ def _run_kernel(esp: EncodedSearch, es: EncodedSearch, model: ModelSpec,
     timed_out = False
     low_streak = 0  # consecutive slices whose live width fit a lower rung
     per_lvl: float | None = None  # measured seconds/level at width F
-    prev_depth = int(np.asarray(carry[4]))
+    prev_depth = int(_entry_depth(*(int(np.asarray(carry[i]))
+                                    for i in (1, 3, 4))))
     hard_s = _slice_hard_s()
     tele_on = _tele.enabled()
     acc = _tele.SearchTelemetry() if tele_on else None
@@ -2229,12 +2247,10 @@ def _run_kernel(esp: EncodedSearch, es: EncodedSearch, model: ModelSpec,
         fn = get_kernel(model, dims, masked=_masked,
                         masked_crash=_mcrash, dedup=_dedup, vt=_vt,
                         telemetry=tele_on)
-        _trace(f"run F={F} cap={lvl_cap} first={int(first)} "
-               f"depth={prev_depth}")
         t0 = time.perf_counter()
         tele_buf = None
         with obs.span("device.slice", cat="device", frontier=F,
-                      levels=lvl_cap, first=first):
+                      levels=lvl_cap, first=first) as sp:
             res = fn(*args, jnp.int32(budget), jnp.int32(lvl_cap),
                      jnp.bool_(bail), *carry)
             if tele_on:
@@ -2242,28 +2258,27 @@ def _run_kernel(esp: EncodedSearch, es: EncodedSearch, model: ModelSpec,
             else:
                 carry = res
             jax.block_until_ready(carry)
+            status = int(carry[2])
+            count = int(carry[1])
+            configs = int(carry[3])
+            ovf = bool(carry[5])
+            depth = int(carry[4])
+            levels_run = depth - prev_depth
+            sp.set(count=count, configs=configs, depth=depth,
+                   levels_run=levels_run, overflow=ovf, status=status)
+        prev_depth = depth
         used_pallas = used_pallas or want_pallas
         dt = time.perf_counter() - t0
         _tele.record_device_seconds(dt)
         if acc is not None and tele_buf is not None:
             acc.add_slice(np.asarray(tele_buf), t0, t0 + dt,
-                          frontier=F)
+                          frontier=F, levels=levels_run, parent=sp.id)
         if on_slice is not None:
             _RUN_PALLAS.flag = used_pallas
             try:
                 on_slice(carry, dims)
             finally:
                 _RUN_PALLAS.flag = None
-        status = int(carry[2])
-        count = int(carry[1])
-        configs = int(carry[3])
-        ovf = bool(carry[5])
-        depth = int(carry[4])
-        _trace(f"done F={F} cap={lvl_cap} dt={dt:.3f}s count={count} "
-               f"configs={configs} depth={depth} ovf={int(ovf)} "
-               f"status={status}")
-        levels_run = depth - prev_depth
-        prev_depth = depth
         if not first and levels_run > 0:
             per_lvl = dt / levels_run
         if status != -1 or count <= 0 or configs >= budget:
@@ -2459,15 +2474,20 @@ def search_opseq(seq: OpSeq, model: ModelSpec, *,
             maybe_audit(seq, model, dict(hbres.decided), audit),
             hbres=hbres)
 
-    es = encode_search(seq)
+    with obs.span("linearizable.prep", rows=len(seq)):
+        es = encode_search(seq)
     if es.n_det == 0 and es.n_crash == 0:
         return finish({"valid": True, "configs": 0, "max_depth": 0,
                        "engine": "trivial", "linearization": []})
-    if greedy_witness(seq, model):
+    with obs.span("linearizable.witness") as sp:
+        found = greedy_witness(seq, model)
+        lin = greedy_linearization(seq) if found else None
+        sp.set(found=found)
+    if found:
         return finish({"valid": True, "configs": es.n_det,
                        "max_depth": es.n_det,
                        "engine": "greedy-witness",
-                       "linearization": greedy_linearization(seq)})
+                       "linearization": lin})
     if es.window > MAX_WINDOW or es.n_crash > MAX_CRASH:
         # past the device encoding limits: the linear host sweep has no
         # window/crash caps and dominates the WGL DFS on exactly the
@@ -2480,27 +2500,29 @@ def search_opseq(seq: OpSeq, model: ModelSpec, *,
         out["engine"] = "host-linear(fallback)"
         return finish(out)
 
-    dims = dims or choose_dims(es, model)
-    dpor_stats = None
-    if resolve_dpor(dpor):
-        attach_reductions(es, seq, model,
-                          hbres.must_pred if hbres is not None
-                          else None, dedup=True)
-        _strip_reductions_for_pallas(es, model, dims)
-        n_mask_rows = 0
-        if es.det_mpred is not None:
-            n_mask_rows = int(
-                ((es.det_mpred[:, 0] >= 0)
-                 | (es.det_cpred != 0)).sum()
-                + ((es.crash_mpred[:, 0] >= 0)
-                   | (es.crash_cpred != 0)).sum())
-        dpor_stats = {"enabled": True, "device_masked": es.masked,
-                      "device_mask_rows": n_mask_rows,
-                      "dedup": es.dedup}
-        if es.masked:
-            _M_MASK.inc(dpor_stats["device_mask_rows"],
-                        site="device-rows")
-    esp = pad_search(es, dims.n_det_pad, dims.n_crash_pad)
+    with obs.span("linearizable.prep", rows=len(seq)) as sp:
+        dims = dims or choose_dims(es, model)
+        sp.set(frontier=dims.frontier)
+        dpor_stats = None
+        if resolve_dpor(dpor):
+            attach_reductions(es, seq, model,
+                              hbres.must_pred if hbres is not None
+                              else None, dedup=True)
+            _strip_reductions_for_pallas(es, model, dims)
+            n_mask_rows = 0
+            if es.det_mpred is not None:
+                n_mask_rows = int(
+                    ((es.det_mpred[:, 0] >= 0)
+                     | (es.det_cpred != 0)).sum()
+                    + ((es.crash_mpred[:, 0] >= 0)
+                       | (es.crash_cpred != 0)).sum())
+            dpor_stats = {"enabled": True, "device_masked": es.masked,
+                          "device_mask_rows": n_mask_rows,
+                          "dedup": es.dedup}
+            if es.masked:
+                _M_MASK.inc(dpor_stats["device_mask_rows"],
+                            site="device-rows")
+        esp = pad_search(es, dims.n_det_pad, dims.n_crash_pad)
     status, configs, max_depth, dims, used_pallas, tele_acc = \
         _run_kernel(esp, es, model, dims, budget, on_slice=on_slice,
                     deadline=deadline, stop=stop)
@@ -3255,6 +3277,13 @@ def _drive_batch_compacting(fn, esps, model: ModelSpec, dims: SearchDims,
     # re-stacks, so carry rows and keys never misalign; retired keys
     # keep their dead lane until the next grid shrink)
     args, carry = stack(lanes, [row0] * n)
+    # the lanes' depths before the slice: the kernel loops until its
+    # last lane stops, so the most levels any lane ran is the levels it
+    # ran
+    def entry(c):
+        return _entry_depth(*(np.asarray(c[i]) for i in (1, 3, 4)))
+
+    depth0 = entry(carry) if tele_acc is not None else None
 
     lvl_cap = _SLICE_LEVELS0
     first = True
@@ -3267,7 +3296,10 @@ def _drive_batch_compacting(fn, esps, model: ModelSpec, dims: SearchDims,
             # only the lane-sum aggregate is meaningful — totals-only
             carry = res[:6]
             jax.block_until_ready(carry)
-            tele_acc.add_totals(np.asarray(res[6]))
+            depth1 = np.asarray(carry[4])
+            tele_acc.add_totals(np.asarray(res[6]),
+                                levels=int((depth1 - depth0).max()))
+            depth0 = depth1
         else:
             carry = res
             jax.block_until_ready(carry)
@@ -3300,6 +3332,8 @@ def _drive_batch_compacting(fn, esps, model: ModelSpec, dims: SearchDims,
             rows = [tuple(np.asarray(c)[i] for c in carry) for i in live]
             lanes = [lanes[i] for i in live]
             args, carry = stack(lanes, rows)
+            if tele_acc is not None:
+                depth0 = entry(carry)
             first = True  # new shape: next slice may include a compile
 
     out = np.zeros((5, n), np.int64)
@@ -3623,12 +3657,15 @@ def _search_batch_sharded_fixed(seqs: list[OpSeq],
             t1 = time.perf_counter()
             try:
                 blk = np.asarray(res[6])
+                ran = np.asarray(res[4]) - _entry_depth(
+                    *(np.asarray(c[i]) for i in (1, 3, 4)))
             except Exception:  # noqa: BLE001 — non-addressable
                 pass           # multi-process shards: skip
             else:
                 # inert mesh-divisibility pad lanes excluded BEFORE
                 # the lane-sum: their rows must not bill occupancy
-                tele_acc.add_totals(blk[:len(seqs)])
+                tele_acc.add_totals(blk[:len(seqs)],
+                                    levels=int(ran[:len(seqs)].max()))
                 _tele.emit_shard_levels(blk, len(seqs), n_shards,
                                         t0, t1)
             res = res[:6]
@@ -3643,6 +3680,22 @@ def _search_batch_sharded_fixed(seqs: list[OpSeq],
     def is_active(c):
         return bool(active_fn(c[2], c[1], c[3]))
 
+    def describe(before, after):
+        # per-lane carries, summed (count, configs, lanes overflowed,
+        # lanes still searching) or maxed (depth, levels run) over the
+        # real lanes; nothing where the carry spans processes
+        if not getattr(after[2], "is_fully_addressable", True):
+            return {}
+        n = len(seqs)
+        st, ct, cf, d1, ov = (np.asarray(after[i])[:n]
+                              for i in (2, 1, 3, 4, 5))
+        d0 = _entry_depth(*(np.asarray(before[i])[:n] for i in (1, 3, 4)))
+        return {"count": int(ct.sum()), "configs": int(cf.sum()),
+                "depth": int(d1.max()),
+                "levels_run": int((d1 - d0).max()),
+                "overflow": int(ov.sum()),
+                "status": int((st == -1).sum())}
+
     def gather(x):
         if getattr(x, "is_fully_addressable", True):
             return np.asarray(x)
@@ -3651,7 +3704,7 @@ def _search_batch_sharded_fixed(seqs: list[OpSeq],
         return np.asarray(
             multihost_utils.process_allgather(x, tiled=True))
 
-    carry = _drive_slices(call, carry, is_active)
+    carry = _drive_slices(call, carry, is_active, describe=describe)
     status = gather(carry[2])
     count = gather(carry[1])
     configs = gather(carry[3])
@@ -4074,6 +4127,12 @@ class Linearizable:
                 f"{sorted(self.ALGORITHMS)}") from None
 
     def check(self, test, history, opts=None):
+        # the spans of this call, nested checkers' included, share one
+        # check id; no span encloses the call itself
+        with obs.check_scope():
+            return self._check(test, history, opts)
+
+    def _check(self, test, history, opts):
         model = self.model or test.get("model")
         if model is None:
             raise ValueError("linearizable checker needs a model")
@@ -4089,12 +4148,16 @@ class Linearizable:
             # encode_ops can silently mis-pair the malformed events —
             # and check_safe turns that into an "unknown" verdict
             # carrying the diagnostic, never a wrong True/False.
-            if isinstance(history, OpSeq):
-                lint_warnings = check_opseq_lint(history, model)
-            else:
-                lint_warnings = check_history(history, model)
-        seq = history if isinstance(history, OpSeq) else \
-            encode_ops(history, model.f_codes)
+            with obs.span("linearizable.lint", rows=len(history)):
+                if isinstance(history, OpSeq):
+                    lint_warnings = check_opseq_lint(history, model)
+                else:
+                    lint_warnings = check_history(history, model)
+        if isinstance(history, OpSeq):
+            seq = history
+        else:
+            with obs.span("linearizable.encode", rows=len(history)):
+                seq = encode_ops(history, model.f_codes)
         if self.explain:
             # plan-only mode (--explain): report what the search WOULD
             # do — dims, bucket, route, decompositions — and stop
@@ -4218,22 +4281,27 @@ class Linearizable:
                 return out
             # exact confirmation + witness for the report, on the
             # shortest sound prefix covering the failure region
-            target = seq
-            trunc = truncate_to_failure(seq, out.get("max_depth", 0),
-                                        out.get("window", 1))
-            if trunc is not None:
-                target = trunc
-            if len(target) <= self.witness_threshold:
-                confirm = seqmod.check_opseq(target, model, lint=False)
-                if confirm["valid"] is False:
-                    confirm["engine"] = out["engine"] + "+host-witness"
-                    confirm["device_configs"] = out["configs"]
-                    confirm["witness_prefix_ops"] = len(target)
-                    self._render_failure(test, target, confirm, opts,
-                                         model)
-                    return confirm
-                # prefix came back valid: fall through to the full
-                # device verdict (obstruction lies past the cut)
+            confirm = None
+            with obs.span("linearizable.confirm") as sp:
+                target = seq
+                trunc = truncate_to_failure(seq, out.get("max_depth", 0),
+                                            out.get("window", 1))
+                if trunc is not None:
+                    target = trunc
+                if len(target) <= self.witness_threshold:
+                    confirm = seqmod.check_opseq(target, model,
+                                                 lint=False)
+                    sp.set(configs=confirm.get("configs"),
+                           valid=confirm["valid"])
+                sp.set(prefix_ops=len(target))
+            if confirm is not None and confirm["valid"] is False:
+                confirm["engine"] = out["engine"] + "+host-witness"
+                confirm["device_configs"] = out["configs"]
+                confirm["witness_prefix_ops"] = len(target)
+                self._render_failure(test, target, confirm, opts, model)
+                return confirm
+            # no confirmation, or the prefix came back valid: the full
+            # device verdict stands (obstruction lies past the cut)
         return out
 
     #: don't delta-debug failure reports past this many rows — each
@@ -4257,12 +4325,14 @@ class Linearizable:
             if self.shrink if self.shrink is not None \
                     else shrink_enabled():
                 try:
-                    s = shrink_invalid(seq, model)
+                    with obs.span("linearizable.shrink", rows=len(seq)):
+                        s = shrink_invalid(seq, model)
                     result["shrink"] = shrink_summary(seq, s)
                 except Exception:  # noqa: BLE001 — reporting only
                     pass
-        path = linear_report.write_linear_html(test or {}, seq, result,
-                                               opts)
+        with obs.span("linearizable.report", rows=len(seq)):
+            path = linear_report.write_linear_html(test or {}, seq,
+                                                   result, opts)
         if path is not None:
             result["report_file"] = path
 

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Iterable
 
+from . import obs
 from .checker.core import Checker, check_safe, merge_valid
 from .history import Op
 from .util import bounded_pmap
@@ -225,14 +226,22 @@ class IndependentChecker(Checker):
         chk: Linearizable = self.checker
         model = chk.model or test.get("model")
         keys = list(subhistories)
-        seqs = [encode_ops(subhistories[k], model.f_codes) for k in keys]
+        with obs.span("independent.encode", keys=len(keys)):
+            seqs = [encode_ops(subhistories[k], model.f_codes)
+                    for k in keys]
+
+        def one(i, why):
+            with obs.span("independent.key", key=keys[i],
+                          rows=len(seqs[i]), why=why):
+                return check_safe(chk, test, subhistories[keys[i]])
+
         # tiny histories aren't worth a device roundtrip; knossos-style
         # host checks for them, batch the rest
         small = [i for i, s in enumerate(seqs)
                  if len(s) <= chk.host_threshold]
         results: dict = {}
         for i in small:
-            results[keys[i]] = check_safe(chk, test, subhistories[keys[i]])
+            results[keys[i]] = one(i, "small")
         big = [i for i in range(len(keys)) if i not in set(small)]
         if big:
             batch = search_batch([seqs[i] for i in big], model,
@@ -240,17 +249,24 @@ class IndependentChecker(Checker):
             for i, r in zip(big, batch):
                 if r["valid"] is False:
                     # exact host confirmation + witness, as in the solo path
-                    results[keys[i]] = check_safe(
-                        chk, test, subhistories[keys[i]])
+                    results[keys[i]] = one(i, "recheck")
                 else:
                     results[keys[i]] = r
         return results
 
     def check(self, test, history, opts=None):
+        # the spans of this call, the keys' checkers' included, share
+        # one check id; no span encloses the call itself
+        with obs.check_scope():
+            return self._check(test, history, opts)
+
+    def _check(self, test, history, opts):
         from .checker.linearizable import Linearizable
 
-        ks = history_keys(history)
-        subs = {k: subhistory(k, history) for k in ks}
+        with obs.span("independent.split", events=len(history)) as sp:
+            ks = history_keys(history)
+            subs = {k: subhistory(k, history) for k in ks}
+            sp.set(keys=len(ks))
         if self.batch_device and isinstance(self.checker, Linearizable):
             results = self._device_batch(test, subs)
         else:

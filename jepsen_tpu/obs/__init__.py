@@ -34,9 +34,10 @@ import logging
 from . import metrics  # noqa: F401  (the registry half)
 from . import telemetry  # noqa: F401  (the device-search aux block)
 from .metrics import REGISTRY  # noqa: F401
-from .trace import (DEFAULT_CAP, SpanRecorder, chrome_trace,  # noqa: F401
-                    current_run, drop_recorder, enable, enabled,
-                    recorder, set_run, span, traced, write_trace)
+from .trace import (DEFAULT_CAP, SpanRecorder, check_scope,  # noqa: F401
+                    chrome_trace, current_run, drop_recorder, enable,
+                    enabled, recorder, set_run, span, traced,
+                    write_trace)
 
 
 class _CtxAdapter(logging.LoggerAdapter):

@@ -375,7 +375,7 @@ def _declare(reg: Registry) -> None:
     # device-search telemetry (obs/telemetry.py): what the kernels did
     # inside their device.slice windows, level by level
     reg.counter("jtpu_search_levels_total",
-                "Device BFS levels executed (telemetry-observed)")
+                "Device BFS levels executed (each slice's depth delta)")
     reg.counter("jtpu_search_expanded_total",
                 "Valid candidate lanes expanded by device BFS levels")
     reg.counter("jtpu_search_mask_killed_total",

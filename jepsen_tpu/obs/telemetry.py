@@ -132,7 +132,7 @@ def enable(on: bool | None = True) -> None:
 
 _M_LEVELS = _metrics.REGISTRY.counter(
     "jtpu_search_levels_total",
-    "Device BFS levels executed (telemetry-observed)")
+    "Device BFS levels executed (each slice's depth delta)")
 _M_EXP = _metrics.REGISTRY.counter(
     "jtpu_search_expanded_total",
     "Valid candidate lanes expanded by device BFS levels")
@@ -223,32 +223,38 @@ class SearchTelemetry:
         self.truncated = False  # some slice folded levels into its
         #                         last row (lvl_cap > TELE_ROWS)
 
-    def _tally(self, rows: list[dict]) -> None:
+    def _tally(self, rows: list[dict], levels: int) -> None:
         for r in rows:
             for name in COLUMNS:
                 self.totals[name] += r[name]
             self.max_occupancy = max(self.max_occupancy, r["occupancy"])
-        self.n_levels += len(rows)
+        self.n_levels += levels
 
     def add_slice(self, tele: np.ndarray, t0: float | None = None,
                   t1: float | None = None,
-                  frontier: int | None = None) -> None:
+                  frontier: int | None = None, *, levels: int,
+                  parent=_trace._ON_THREAD) -> None:
         """Ingest one slice's aux block.  ``t0``/``t1`` (perf_counter
         readings of the slice window) enable ``device.level`` child
         span emission, apportioned by occupancy — per-level cost is
         proportional to frontier width, so occupancy is the honest
-        cheap estimator."""
+        cheap estimator.  ``levels`` is the levels the slice ran (the
+        carry's depth after it less before): the level count takes it,
+        where the block's rows would miss every level folded into the
+        last row.  ``parent`` is the id of the slice's ``device.slice``
+        span, when it has closed."""
         rows = unpack_levels(tele)
         self.slices += 1
-        if not rows:
-            return
         t = np.asarray(tele)
-        if int(t[TELE_ROWS - 1, C_OCC]) > 0 and len(rows) == TELE_ROWS:
+        if rows and int(t[TELE_ROWS - 1, C_OCC]) > 0 \
+                and len(rows) == TELE_ROWS:
             # the last row is additive: with every row written it may
             # hold the fold of any levels past the buffer
             self.truncated = True
-        base_level = self.n_levels
-        self._tally(rows)
+        base_level = len(self.levels)
+        self._tally(rows, levels)
+        if not rows:
+            return
         self.levels.extend(rows)
         if t0 is not None and t1 is not None and _trace.enabled():
             rec = _trace.recorder(_trace.current_run())
@@ -261,23 +267,21 @@ class SearchTelemetry:
                 args = {"level": base_level + i, **r}
                 if frontier is not None:
                     args["frontier"] = frontier
-                rec.record("device.level", "device", cur, end, args)
+                rec.record("device.level", "device", cur, end, args,
+                           parent=parent)
                 cur = end
 
-    def add_totals(self, tele: np.ndarray) -> None:
+    def add_totals(self, tele: np.ndarray, *, levels: int) -> None:
         """Ingest an aggregate block (e.g. a batch's lane-sum): totals
         and level count only — per-level rows across differently-paced
-        keys do not align, so none are kept."""
+        keys do not align, so none are kept.  ``levels`` is the levels
+        the batch kernel ran (its deepest lane's depth delta), counted
+        as in `add_slice`."""
         t = np.asarray(tele)
         if t.ndim == 3:
             t = t.sum(axis=0)
-        rows = unpack_levels(t)
         self.slices += 1
-        for r in rows:
-            for name in COLUMNS:
-                self.totals[name] += r[name]
-            self.max_occupancy = max(self.max_occupancy, r["occupancy"])
-        self.n_levels += len(rows)
+        self._tally(unpack_levels(t), levels)
 
     def block(self, predicted: float | None = None) -> dict:
         """The ``search_telemetry`` result block.  ``predicted`` is
@@ -311,7 +315,7 @@ class SearchTelemetry:
         if per:
             out["per_level"] = per
             out["per_level_columns"] = list(COLUMNS)
-            if self.n_levels > len(per):
+            if len(self.levels) > len(per):
                 out["per_level_capped"] = True
         return out
 

@@ -27,12 +27,21 @@ Perfetto / ``chrome://tracing`` — via :func:`chrome_trace` /
 when tracing is on; ``python -m jepsen_tpu.obs trace <run>`` re-emits
 it and ``tools/trace_report.py`` folds it into a phase-time table.
 
+Each recorded span carries an ``id``, the ``parent`` span open on its
+thread when it began, and the ``check`` it belongs to: an integer the
+outermost checker call opens with :func:`check_scope` and holds in a
+contextvar, so a thread started with a copied context (the bucket prep
+thread) attributes its spans to the same check.  The Chrome export
+carries all three in ``args``.
+
 Zero dependencies; threads are first-class (the recorder appends are
 atomic, thread names become Perfetto track names).
 """
 
 from __future__ import annotations
 
+import contextvars
+import itertools
 import json
 import os
 import threading
@@ -69,6 +78,26 @@ _env_on: bool | None = None
 #: locking, sound here because the GIL makes the ``_env_on`` load
 #: atomic and the value is computed idempotently from the env
 _knob_lock = threading.Lock()
+
+#: span and check ids, process-wide (``next`` on a count is atomic
+#: under the GIL)
+_span_ids = itertools.count(1)
+_check_ids = itertools.count(1)
+#: per thread, the ids of the spans open on it, innermost last
+_open = threading.local()
+#: the check the current context works for (see :func:`check_scope`)
+_CHECK: contextvars.ContextVar = contextvars.ContextVar(
+    "jepsen_tpu_check", default=None)
+#: `SpanRecorder.record`'s default parent and check: what is open on
+#: the recording thread
+_ON_THREAD = object()
+
+
+def _open_stack() -> list:
+    st = getattr(_open, "stack", None)
+    if st is None:
+        st = _open.stack = []
+    return st
 
 
 def enabled() -> bool:
@@ -121,9 +150,19 @@ class SpanRecorder:
         return len(self._spans)
 
     def record(self, name: str, cat: str, t0: float, t1: float,
-               args: dict | None = None) -> None:
+               args: dict | None = None, *, sid: int | None = None,
+               parent=_ON_THREAD, check=_ON_THREAD) -> None:
         """Record one finished span; ``t0``/``t1`` are
-        ``time.perf_counter()`` readings."""
+        ``time.perf_counter()`` readings.  Without ``sid`` the span
+        takes a fresh id; without ``parent``, the span open on this
+        thread; without ``check``, the current check."""
+        if sid is None:
+            sid = next(_span_ids)
+        if parent is _ON_THREAD:
+            st = _open_stack()
+            parent = st[-1] if st else None
+        if check is _ON_THREAD:
+            check = _CHECK.get()
         if len(self._spans) == self.cap:
             self.dropped += 1
         self._spans.append({
@@ -132,6 +171,7 @@ class SpanRecorder:
             "dur": round((t1 - t0) * 1e6, 1),
             "tid": threading.current_thread().name,
             "args": args or {},
+            "id": sid, "parent": parent, "check": check,
         })
 
     def spans(self) -> list[dict]:
@@ -151,7 +191,9 @@ class SpanRecorder:
             events.append({"name": s["name"], "cat": s["cat"],
                            "ph": "X", "ts": s["ts"], "dur": s["dur"],
                            "pid": pid, "tid": tid,
-                           "args": s["args"]})
+                           "args": {**s["args"], "id": s.get("id"),
+                                    "parent": s.get("parent"),
+                                    "check": s.get("check")}})
         meta = [{"name": "thread_name", "ph": "M", "pid": pid,
                  "tid": t, "args": {"name": n}}
                 for n, t in tids.items()]
@@ -209,6 +251,7 @@ class _Noop:
     truthiness check, allocates nothing."""
 
     __slots__ = ()
+    id = None
 
     def __enter__(self):
         return self
@@ -216,12 +259,16 @@ class _Noop:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NOOP = _Noop()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "run", "args", "_t0")
+    __slots__ = ("name", "cat", "run", "args", "_t0", "id", "parent",
+                 "check")
 
     def __init__(self, name: str, cat: str, run: str | None,
                  args: dict | None):
@@ -230,19 +277,36 @@ class _Span:
         self.run = run
         self.args = args
 
+    def set(self, **attrs) -> None:
+        """Add attrs known only once the work has run (a verdict, a
+        count); they land in the recorded span's args."""
+        self.args = {**(self.args or {}), **attrs}
+
     def __enter__(self):
+        self.id = next(_span_ids)
+        self.check = _CHECK.get()
+        st = _open_stack()
+        self.parent = st[-1] if st else None
+        st.append(self.id)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        st = _open_stack()
+        if st and st[-1] == self.id:
+            st.pop()
+        elif self.id in st:
+            st.remove(self.id)
         args = self.args
         if exc_type is not None:
             args = dict(args or {})
             args["error"] = exc_type.__name__
         run = self.run if self.run is not None else _current_run
         try:
-            recorder(run).record(self.name, self.cat, self._t0, t1, args)
+            recorder(run).record(self.name, self.cat, self._t0, t1, args,
+                                 sid=self.id, parent=self.parent,
+                                 check=self.check)
         except Exception:  # pragma: no cover — the recorder must never
             pass           # take down the instrumented code
         return False
@@ -255,6 +319,28 @@ def span(name: str, *, cat: str = "span", run: str | None = None,
     if not enabled():
         return _NOOP
     return _Span(name, cat, run, attrs or None)
+
+
+class _CheckScope:
+    __slots__ = ("_token",)
+
+    def __enter__(self):
+        self._token = _CHECK.set(next(_check_ids))
+        return self
+
+    def __exit__(self, *exc):
+        _CHECK.reset(self._token)
+        return False
+
+
+def check_scope():
+    """``with obs.check_scope(): ...`` around a checker call: the spans
+    recorded inside carry one fresh ``check`` id.  Only the outermost
+    call opens one (a nested checker's spans belong to the caller's
+    check); no-op when tracing is off."""
+    if not enabled() or _CHECK.get() is not None:
+        return _NOOP
+    return _CheckScope()
 
 
 def traced(name: str | None = None, *, cat: str = "span"):

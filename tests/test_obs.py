@@ -676,3 +676,152 @@ def test_phase_times_from_history():
     assert ph["check"] == 1.0
     assert ph["nemesis"] == pytest.approx(0.5)
     assert ph["heal"] == pytest.approx(0.25)
+
+
+# ---------------------------------------------------------------------------
+# checker phase spans and span identity
+# ---------------------------------------------------------------------------
+
+
+def _stale_events(rounds, key=None):
+    """``rounds`` rounds of a write racing a read of its value, then a
+    read of a value a later write overwrote: invalid, and left to the
+    device search by the greedy witness and the prepass.  Values are
+    wrapped for ``key`` when one is given."""
+    from jepsen_tpu.independent import KV
+
+    def v(x):
+        return x if key is None else KV(key, x)
+
+    h = []
+    for i in range(rounds):
+        h += [invoke_op(0, "write", v(i % 5)), invoke_op(1, "read", v(None)),
+              ok_op(0, "write", v(i % 5)), ok_op(1, "read", v(i % 5))]
+    h += [invoke_op(0, "write", v(1)), ok_op(0, "write", v(1)),
+          invoke_op(0, "write", v(2)), ok_op(0, "write", v(2)),
+          invoke_op(1, "read", v(None)), ok_op(1, "read", v(1))]
+    return h
+
+
+def _checked_spans(chk, history, tmp_path):
+    obs.set_run(None)
+    obs.recorder(None).clear()
+    test = {"name": "spans", "store_base": str(tmp_path),
+            "start_time": "t"}
+    return chk.check(test, history), obs.recorder(None).spans()
+
+
+def _assert_links(spans):
+    """Every parent is a recorded span of the same thread whose interval
+    holds the child's (a `device.level` hangs off its `device.slice`,
+    whose wall it apportions)."""
+    by_id = {s["id"]: s for s in spans}
+    assert len(by_id) == len(spans)
+    for s in spans:
+        if s["parent"] is None:
+            continue
+        p = by_id[s["parent"]]
+        assert p["tid"] == s["tid"]
+        if s["name"] == "device.level":
+            assert p["name"] == "device.slice"
+            continue
+        assert p["ts"] <= s["ts"] + 0.2
+        assert s["ts"] + s["dur"] <= p["ts"] + p["dur"] + 0.2
+
+
+def test_device_check_records_phase_spans(tracing, tmp_path):
+    from jepsen_tpu.checker.linearizable import Linearizable
+    from jepsen_tpu.models import cas_register
+
+    chk = Linearizable(cas_register(0), algorithm="device")
+    r, spans = _checked_spans(chk, _stale_events(30), tmp_path)
+    assert r["valid"] is False
+    names = {s["name"] for s in spans}
+    for n in ("linearizable.lint", "linearizable.encode",
+              "linearizable.prep", "linearizable.witness",
+              "linearizable.confirm", "linearizable.shrink",
+              "linearizable.report", "device.slice", "device.level"):
+        assert n in names, n
+    by = {s["name"]: s for s in spans}
+    assert by["linearizable.witness"]["args"] == {"found": False}
+    conf = by["linearizable.confirm"]["args"]
+    assert conf["valid"] is False and conf["configs"] > 0
+    assert conf["prefix_ops"] == r["witness_prefix_ops"]
+    assert by["device.slice"]["args"]["levels_run"] > 0
+    # one check id for the whole call, a fresh one for the next
+    checks = {s["check"] for s in spans}
+    assert len(checks) == 1 and None not in checks
+    _assert_links(spans)
+    # no span encloses the call: its phases are roots
+    roots = {s["name"] for s in spans if s["parent"] is None}
+    assert {"linearizable.lint", "linearizable.confirm",
+            "linearizable.report"} <= roots
+    _r, spans2 = _checked_spans(chk, _stale_events(30), tmp_path)
+    assert {s["check"] for s in spans2} == {checks.pop() + 1}
+
+
+def test_tracing_off_records_no_span(tmp_path):
+    from jepsen_tpu.checker.linearizable import Linearizable
+    from jepsen_tpu.models import cas_register
+
+    obs.enable(False)
+    try:
+        chk = Linearizable(cas_register(0), algorithm="device")
+        r, spans = _checked_spans(chk, _stale_events(30), tmp_path)
+    finally:
+        obs.enable(None)
+    assert r["valid"] is False
+    assert spans == []
+    assert obs.check_scope() is obs.span("x")  # the shared no-op
+
+
+def test_independent_checker_records_split_encode_key(tracing, tmp_path):
+    from jepsen_tpu import independent
+    from jepsen_tpu.checker.linearizable import Linearizable
+    from jepsen_tpu.models import cas_register
+
+    chk = independent.checker(
+        Linearizable(cas_register(0), algorithm="device"))
+    # key 0 invalid and long enough for the device batch, key 1 valid,
+    # key 2 too short for it
+    h = (_stale_events(30, key=0) + _stale_events(30, key=1)[:-6]
+         + _stale_events(2, key=2)[:-6])
+    r, spans = _checked_spans(chk, h, tmp_path)
+    assert r["valid"] is False and r["failures"] == [0]
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s)
+    (split,) = by["independent.split"]
+    assert split["args"] == {"events": len(h), "keys": 3}
+    (enc,) = by["independent.encode"]
+    assert enc["args"] == {"keys": 3}
+    keys = {s["args"]["key"]: s["args"] for s in by["independent.key"]}
+    assert keys[0]["why"] == "recheck" and keys[2]["why"] == "small"
+    assert 1 not in keys
+    assert keys[0]["rows"] == 63  # encoded ops
+    # the prep thread's spans belong to the same check
+    assert by["bucket.prep"][0]["tid"] != split["tid"]
+    assert len({s["check"] for s in spans}) == 1
+    _assert_links(spans)
+    # the re-checked key's phases hang off its independent.key span
+    key0 = next(s for s in by["independent.key"]
+                if s["args"]["key"] == 0)
+    assert any(s["parent"] == key0["id"]
+               for s in by["linearizable.confirm"])
+
+
+def test_chrome_trace_carries_span_identity(tracing):
+    run = "t-ident"
+    obs.drop_recorder(run)
+    with obs.check_scope():
+        with obs.span("a", run=run):
+            with obs.check_scope():  # nested: keeps the outer check
+                with obs.span("b", run=run, rows=1):
+                    pass
+    xs = {e["name"]: e["args"]
+          for e in obs.chrome_trace(run)["traceEvents"] if e["ph"] == "X"}
+    assert xs["b"]["parent"] == xs["a"]["id"]
+    assert xs["a"]["parent"] is None
+    assert xs["a"]["check"] == xs["b"]["check"] is not None
+    assert xs["b"]["rows"] == 1
+    obs.drop_recorder(run)

@@ -178,7 +178,7 @@ def test_accumulator_totals_truncation_and_block():
     blk = np.zeros((tele.TELE_ROWS, tele.TELE_COLS), np.int32)
     for i in range(tele.TELE_ROWS):
         blk[i] = (2, 4, 1, 0, 0, 2, 0, 0)
-    acc.add_slice(blk)
+    acc.add_slice(blk, levels=tele.TELE_ROWS)
     # every row written incl. the additive last one -> truncated
     assert acc.truncated is True
     assert acc.n_levels == tele.TELE_ROWS
@@ -196,7 +196,7 @@ def test_accumulator_per_level_cap():
     blk = np.zeros((tele.TELE_ROWS, tele.TELE_COLS), np.int32)
     blk[:, tele.C_OCC] = 1
     for _ in range(8):  # 8 x 128 levels > BLOCK_LEVEL_CAP
-        acc.add_slice(blk)
+        acc.add_slice(blk, levels=tele.TELE_ROWS)
     out = acc.block()
     assert len(out["per_level"]) == tele.BLOCK_LEVEL_CAP
     assert out["per_level_capped"] is True
@@ -207,7 +207,7 @@ def test_add_totals_folds_batched_blocks():
     acc = tele.SearchTelemetry()
     blk = np.zeros((3, tele.TELE_ROWS, tele.TELE_COLS), np.int32)
     blk[:, 0] = (5, 7, 1, 0, 0, 5, 0, 1)
-    acc.add_totals(blk)  # 3-D: lane-sum first
+    acc.add_totals(blk, levels=1)  # 3-D: lane-sum first
     assert acc.totals["occupancy"] == 15
     assert acc.totals["expanded"] == 21
     assert acc.levels == []  # totals-only: no per-level rows kept
@@ -561,3 +561,64 @@ def test_sharded_route_telemetry_block(mesh):
             assert st["observed_prune_ratio"] is not None
             return
     pytest.fail("no sharded search produced device telemetry")
+
+
+# ---------------------------------------------------------------------------
+# The level count is the levels the slices ran, not the aux rows
+# ---------------------------------------------------------------------------
+
+
+def _stale_seq(model, rounds: int):
+    """A history the greedy witness and the prepass leave to the
+    search: ``rounds`` rounds of a write racing a read of its value,
+    then a read of a value a later write overwrote.  One determinate op
+    per BFS level, so the search runs one level per op."""
+    h = []
+    for i in range(rounds):
+        h += [invoke_op(0, "write", i % 5), invoke_op(1, "read", None),
+              ok_op(0, "write", i % 5), ok_op(1, "read", i % 5)]
+    h += [invoke_op(0, "write", 1), ok_op(0, "write", 1),
+          invoke_op(0, "write", 2), ok_op(0, "write", 2),
+          invoke_op(1, "read", None), ok_op(1, "read", 1)]
+    return encode_ops(h, model.f_codes)
+
+
+def _levels_counted(fn):
+    before = REGISTRY.get("jtpu_search_levels_total").total()
+    out = fn()
+    return out, REGISTRY.get("jtpu_search_levels_total").total() - before
+
+
+@pytest.mark.parametrize("slice_levels", [32, 512])
+def test_levels_counter_counts_levels_run_past_the_aux_rows(
+        monkeypatch, slice_levels):
+    """Slices of 512 levels fold every level past row 128 into the aux
+    block's last row; the counter still moves by exactly the levels the
+    search ran, whatever the slicing, while the per-level rows stay the
+    block's."""
+    model = cas_register(0)
+    s = _stale_seq(model, 100)
+    monkeypatch.setattr(lin, "_SLICE_LEVELS0", slice_levels)
+    r, counted = _levels_counted(lambda: lin.search_opseq(s, model))
+    assert r["valid"] is False and _device_searched(r)
+    st = r["search_telemetry"]
+    # a level searches the rows at its depth, the root's 0 included
+    assert counted == st["levels"] == r["max_depth"] + 1 == len(s)
+    assert counted > tele.TELE_ROWS
+    if slice_levels > counted:
+        # one slice: its rows are the block's, the last one folded
+        assert st["slices"] == 1 and st["truncated"] is True
+        assert len(st["per_level"]) == tele.TELE_ROWS
+
+
+def test_batch_levels_counter_counts_the_deepest_lane(monkeypatch):
+    """The vmapped ladder loops until its last lane stops: it counts the
+    levels of its deepest lane, not the lane-summed aux rows."""
+    model = cas_register(0)
+    seqs = [_stale_seq(model, 100), _stale_seq(model, 70)]
+    monkeypatch.setattr(lin, "_SLICE_LEVELS0", 512)
+    rs, counted = _levels_counted(
+        lambda: lin.search_batch(seqs, model, bucket=False))
+    assert [r["valid"] for r in rs] == [False, False]
+    assert counted == max(r["max_depth"] for r in rs) + 1 == len(seqs[0])
+    assert counted > tele.TELE_ROWS

@@ -27,9 +27,13 @@ never — exactly knossos's crashed-op semantics (core.clj:387-397 defines
 how crashed processes arise).
 
 The search is DFS with a visited memo on (linearized-set, state); sets are
-Python bigint bitmasks.  Worst case exponential — ``max_configs`` bounds
-work and yields {"valid": "unknown"} past it, the moral equivalent of the
-reference's -Xmx32g ceiling (jepsen/project.clj:25).
+Python bigint bitmasks.  A configuration's enabled ops are found by walking
+the zero bits of its mask lowest first, up to the first op invoked at or
+after the least pending return: O(window) bit operations a configuration,
+whatever the depth, since already-linearized ops are never visited.  Worst
+case exponential — ``max_configs`` bounds work and yields {"valid":
+"unknown"} past it, the moral equivalent of the reference's -Xmx32g
+ceiling (jepsen/project.clj:25).
 """
 
 from __future__ import annotations
@@ -176,6 +180,7 @@ def check_opseq(seq: OpSeq, model: ModelSpec, *,
     v1 = [int(x) for x in seq.v1]
     v2 = [int(x) for x in seq.v2]
     pystep = model.pystep
+    full = (1 << n) - 1
 
     # must-order mask (HB pre-pass): op j may linearize only once every
     # must-predecessor is in the linearized set — forced edges hold in
@@ -262,25 +267,27 @@ def check_opseq(seq: OpSeq, model: ModelSpec, *,
                            "linearization": lin,
                            "max_depth": len(lin)})
 
-        # Enabled candidates: scan unlinearized ops in invocation order,
-        # maintaining the min return among unlinearized seen so far.  Once
-        # inv[j] >= that min, no later op can be enabled (invocations are
-        # sorted), and the window min equals the global unlinearized min
-        # because any op past the stop point has ret > inv >= stop.
+        # Enabled candidates: visit the unlinearized ops (the zero bits of
+        # mask, lowest first) in invocation order, maintaining the min
+        # return among unlinearized seen so far.  Once inv[j] >= that min,
+        # no later op can be enabled (invocations are sorted), and the
+        # window min equals the global unlinearized min because any op
+        # past the stop point has ret > inv >= stop.  Linearized ops are
+        # never visited: O(window) a configuration, whatever the depth.
         cand: list[int] = []
         rets: list[int] = []
         minret = INF_RET + 1
-        j = 0
-        m = mask
-        while j < n:
-            if not (m >> j) & 1:
-                if inv[j] >= minret:
-                    break
-                cand.append(j)
-                rets.append(ret[j])
-                if ret[j] < minret:
-                    minret = ret[j]
-            j += 1
+        free = full & ~mask
+        while free:
+            low = free & -free
+            j = low.bit_length() - 1
+            if inv[j] >= minret:
+                break
+            cand.append(j)
+            rets.append(ret[j])
+            if ret[j] < minret:
+                minret = ret[j]
+            free ^= low
 
         depth = mask.bit_count()
         if depth > max_depth:

@@ -6,17 +6,11 @@ Everything here is plain Python over plain tuples and imports nothing of
 An event is ``(process, type, f, value)``; a keyed event (etcdemo's
 independent keys) is ``(process, type, f, key, value)``.
 
-Copied from the program, and changed only where noted:
-
-* `register_history` is `jepsen_tpu/synth.register_history`.  Changes:
-  a crashed worker goes on under a fresh process id (p + concurrency),
-  as a Jepsen worker thread does, instead of being retired; ``crashes``
-  crashes exactly that many invocations, so every history does the
-  same amount of indeterminate work; ``init`` sets the register's
-  starting value (ZooKeeper's suite starts at 0).  ``exact`` stops invoking at an exact count of ops that may have
-  taken effect, as `bench._exact_encoded` scanned for.
-* `stale_read` replaces `synth.corrupt_read`: the read returns a value
-  that really was written, but that no linearization can return.
+What is the model's own comes from its file, `models/<model>.py`
+(`spec.model`): ``history(rng, p, n_ops, exact)``, a history valid by
+construction, and ``corrupt(events, p)``, the same history made invalid
+(None where it cannot be).  Here are the layouts that arrange those
+histories into a test, the nemesis, and the pairing of events into ops.
 """
 
 from __future__ import annotations
@@ -25,81 +19,13 @@ import random
 
 INF = float("inf")
 
-#: the fields of a traffic file the generator reads; anything else is
-#: refused so that a typo is not silently a default
-TRAFFIC_KEYS = {"pool", "overlap", "crash_p", "max_crashes", "crashes",
-                "stale_share", "stale_at", "work", "why"}
-
-
-def register_history(rng, *, n_ops, n_procs, overlap=4, crash_p=0.0,
-                     max_crashes=16, crashes=None, n_values=5, cas=True,
-                     init=None, exact=False):
-    """Concurrent CAS-register history, valid by construction: each op
-    takes effect atomically at its completion event.  Returns events
-    ``(process, type, f, value)`` with worker-local process ids.
-    ``exact`` makes ``n_ops`` the count of ops that may have taken
-    effect (ok or info; fails don't count) instead of invocations."""
-    state = init
-    h = []
-    pending = {}            # worker -> (f, value)
-    pid = list(range(n_procs))
-    crash_set = (set(rng.sample(range(n_ops), crashes))
-                 if crashes is not None else None)
-    crash_of = {}           # worker -> whether its pending op crashes
-    n_crashed = 0
-    done = 0
-    failed = 0
-
-    def more():
-        return done - failed < n_ops if exact else done < n_ops
-
-    while more() or pending:
-        free = [w for w in range(n_procs) if w not in pending]
-        if more() and free and (len(pending) < overlap or not pending):
-            w = rng.choice(free)
-            f = rng.choice(["read", "write", "cas"] if cas
-                            else ["read", "write"])
-            if f == "read":
-                v = None
-            elif f == "write":
-                v = rng.randrange(n_values)
-            else:
-                v = (rng.randrange(n_values), rng.randrange(n_values))
-            h.append((pid[w], "invoke", f, v))
-            pending[w] = (f, v)
-            if crash_set is not None:
-                crash_of[w] = done in crash_set
-            done += 1
-            continue
-        w = rng.choice(list(pending))
-        f, v = pending.pop(w)
-        if crash_set is not None:
-            crashed = crash_of.pop(w)
-        else:
-            crashed = (crash_p and rng.random() < crash_p
-                       and n_crashed < max_crashes)
-        if crashed:
-            n_crashed += 1
-            if rng.random() < 0.5:
-                if f == "write":
-                    state = v
-                elif f == "cas" and state == v[0]:
-                    state = v[1]
-            h.append((pid[w], "info", f, v if f != "read" else None))
-            pid[w] += n_procs
-            continue
-        if f == "read":
-            h.append((pid[w], "ok", f, state))
-        elif f == "write":
-            state = v
-            h.append((pid[w], "ok", f, v))
-        elif state == v[0]:
-            state = v[1]
-            h.append((pid[w], "ok", f, v))
-        else:
-            h.append((pid[w], "fail", f, v))
-            failed += 1
-    return h
+#: the fields of a traffic file the layouts read, besides the model's own
+#: (its ``TRAFFIC_KEYS``); anything else is refused so that a typo is not
+#: silently a default.  ``stale_share`` is the share of keys (of
+#: histories, in a single-history layout) that the model's ``corrupt``
+#: makes invalid, for any model
+LAYOUT_KEYS = {"pool", "overlap", "crash_p", "max_crashes", "crashes",
+               "stale_share", "work", "why"}
 
 
 def pair(events):
@@ -121,7 +47,8 @@ def pair(events):
             op["type"] = typ
             if typ == "ok":
                 op["ret"] = i
-                if f == "read":
+                if op["value"] is None:
+                    # what a read (an op invoked with no value) returned
                     op["value"] = v
     return ops
 
@@ -129,46 +56,6 @@ def pair(events):
 def encoded_count(events):
     """Ops that may have taken effect: ok and info, not fail."""
     return sum(1 for op in pair(events) if op["type"] != "fail")
-
-
-def stale_read(events, at):
-    """Rewrite the ok read nearest fraction ``at`` of the way through to
-    a stale value: one that an ok write or cas put in the register
-    before the read was invoked, but that a later op (``barrier``,
-    completed before the read was invoked) overwrote, with no op that
-    could set the value again able to take effect between the barrier
-    and the read.  No linearization can return it, so the history is
-    invalid by construction, and no static pass can refute it from the
-    value alone.  Returns None where no read qualifies."""
-    ops = pair(events)
-    target = int(at * (len(events) - 1))
-    reads = sorted((op for op in ops
-                    if op["f"] == "read" and op["type"] == "ok"
-                    and op["value"] is not None),
-                   key=lambda op: (abs(op["inv"] - target), op["inv"]))
-
-    def sets(op):
-        # the value an op leaves in the register, if it took effect
-        if op["type"] == "fail" or op["f"] == "read":
-            return None
-        return op["value"] if op["f"] == "write" else op["value"][1]
-
-    for r in reads:
-        before = [op for op in ops if op["type"] == "ok"
-                  and op["ret"] < r["inv"] and sets(op) is not None]
-        for v in sorted({sets(op) for op in before} - {r["value"]}):
-            # the last op that could still set v before the read ends,
-            # and the last invoked barrier that left another value
-            last_set = max(op["ret"] for op in ops if sets(op) == v
-                           and op["inv"] < r["ret"])
-            barrier = max((b["inv"] for b in before if sets(b) != v),
-                          default=-1)
-            if barrier > last_set:
-                out = list(events)
-                i = r["ret"]
-                out[i] = out[i][:-1] + (v,)
-                return out
-    return None
 
 
 def nemesis_events(p):
@@ -204,29 +91,25 @@ def interleave(rng, streams, nemesis):
     return out + nemesis[k:]
 
 
-def _history(rng, p, n_ops, exact, stale):
-    """One register history from the seed's ``rng``, redrawn until the
-    stale read has a place."""
+def _history(rng, p, model, n_ops, exact, stale):
+    """One history of the model from the seed's ``rng``; with ``stale``,
+    corrupted, redrawn until the corruption has a place."""
     for _ in range(64):
-        h = register_history(
-            rng, n_ops=n_ops, exact=exact, n_procs=p["procs"],
-            overlap=p["overlap"], crash_p=p.get("crash_p", 0),
-            max_crashes=p.get("max_crashes", 16), crashes=p.get("crashes"),
-            n_values=p["values"], init=p["init"])
+        h = model.history(rng, p, n_ops, exact)
         if not stale:
             return h
-        h = stale_read(h, p["stale_at"])
+        h = model.corrupt(h, p)
         if h is not None:
             return h
-    raise ValueError("no stale read fits 64 histories in a row")
+    raise ValueError("no corruption fits 64 histories in a row")
 
 
-def independent_test(rng, p):
+def independent_test(rng, p, model):
     """One etcdemo test: ``keys_per_test`` keys; ``groups`` thread groups
     of ``procs`` threads each work through the keys in turn
     (independent/concurrent-generator), so group g holds keys g,
     g + groups, ...; a ``stale_share`` of the keys, drawn from the
-    seed, carries a stale read.  Returns (events, truth) where truth
+    seed, is corrupted.  Returns (events, truth) where truth
     maps key -> True if the key's history is valid by construction."""
     n_keys = p["keys_per_test"]
     groups = p["concurrency"] // p["procs"]
@@ -239,7 +122,7 @@ def independent_test(rng, p):
     streams = [[] for _ in range(groups)]
     for k in range(n_keys):
         g = k % groups
-        h = _history(rng, p, p["ops_per_key"], False, k in stale)
+        h = _history(rng, p, model, p["ops_per_key"], False, k in stale)
         top = list(gen_of[g])
         for q, typ, f, v in h:
             w, local = q % procs, q // procs
@@ -252,9 +135,9 @@ def independent_test(rng, p):
     return events, {k: k not in stale for k in range(n_keys)}
 
 
-def single_history(rng, p):
-    """One whole-register history of exactly ``encoded_ops`` ops that may
-    have taken effect, with a stale read at ``stale_at``.  With ``work``
+def single_history(rng, p, model):
+    """One whole history of exactly ``encoded_ops`` ops that may have
+    taken effect, corrupted where ``stale_share`` is set.  With ``work``
     = [lo, hi], redrawn until the plain reference's search visits from
     lo to hi configurations: the configurations a history holds set most
     of what a search of it costs, and they vary twofold from one drawn
@@ -263,8 +146,9 @@ def single_history(rng, p):
 
     lo, hi = p.get("work", (0, INF))
     for _ in range(256):
-        h = _history(rng, p, p["encoded_ops"], True, p.get("stale_share", 0))
-        if lo <= check(rows_of(h), p["init"])[1] <= hi:
+        h = _history(rng, p, model, p["encoded_ops"], True,
+                     p.get("stale_share", 0))
+        if lo <= check(model, rows_of(h), p["init"])[1] <= hi:
             break
     else:
         raise ValueError(f"no history of {lo}-{hi} configurations in 256")
@@ -277,12 +161,12 @@ def single_history(rng, p):
 LAYOUTS = {"independent": independent_test, "single": single_history}
 
 
-def make_pool(seed, config, traffic):
-    """The run's ``pool`` histories (tests, for independent keys), all
-    drawn from the seed and made in set-up."""
-    unknown = set(traffic) - TRAFFIC_KEYS
+def make_pool(seed, config, traffic, model):
+    """The run's ``pool`` histories (tests, for independent keys) of the
+    configuration's model, all drawn from the seed and made in set-up."""
+    unknown = set(traffic) - LAYOUT_KEYS - model.TRAFFIC_KEYS
     if unknown:
         raise ValueError(f"unknown traffic keys {sorted(unknown)}")
     rng = random.Random(f"pool:{int(seed)}")
     p = {**config, **traffic}
-    return [LAYOUTS[p["layout"]](rng, p) for _ in range(p["pool"])]
+    return [LAYOUTS[p["layout"]](rng, p, model) for _ in range(p["pool"])]

@@ -50,10 +50,11 @@ def module_seconds(ctx, pattern):
 LEVEL_KERNELS = r"^jit_step\("
 
 
-def config_bytes(rows, n_values):
+def config_bytes(rows, state_bits):
     """The fewest bytes one search configuration of this history can
     take: its place in the history, one bit per op in the widest window
-    of ops open at once, one per crashed op, and the register's value."""
+    of ops open at once, one per crashed op, and the model's state
+    (``state_bits``, from the model's file)."""
     events = []
     for op in rows:
         if op["type"] == "ok":
@@ -64,14 +65,14 @@ def config_bytes(rows, n_values):
         width = max(width, cur)
     crashed = sum(1 for op in rows if op["type"] != "ok")
     bits = (math.ceil(math.log2(len(rows) + 1)) + width + crashed
-            + math.ceil(math.log2(n_values + 1)))
+            + state_bits)
     return math.ceil(bits / 8)
 
 
-def table_bytes(rows, n_values):
+def table_bytes(rows, state_bits):
     """The fewest bytes of the encoded history a search has to read once:
-    per op its function (2 bits), two values and two event positions."""
+    per op its function (2 bits), two values of ``state_bits`` each and
+    two event positions."""
     n_events = 2 * len(rows)
-    bits = 2 + 2 * math.ceil(math.log2(n_values + 1)) \
-        + 2 * math.ceil(math.log2(n_events + 1))
+    bits = 2 + 2 * state_bits + 2 * math.ceil(math.log2(n_events + 1))
     return math.ceil(len(rows) * bits / 8)

@@ -1,19 +1,22 @@
 """The plain reference: Wing & Gong's search with Lowe's memo, over the
-generator's plain events, for a CAS register.
+generator's plain events, for the configuration's model.
 
 It imports nothing of `jepsen_tpu` and takes nothing the program made.
-A configuration is (set of linearized ops, register value).  From one,
-op j may go next iff it is not linearized, it was invoked before every
-unlinearized ok op returned, and the register allows it.  The history
-is linearizable iff some configuration holds every ok op.  A crashed
-(info) op never returns: it may take effect at any point after its
-invocation, or never.  A read of None is a read whose value is unknown
-and always allowed (knossos's cas-register, as the program's model).
+A configuration is (set of linearized ops, model state).  From one, op j
+may go next iff it is not linearized, it was invoked before every
+unlinearized ok op returned, and the model allows it.  The history is
+linearizable iff some configuration holds every ok op.  A crashed (info)
+op never returns: it may take effect at any point after its invocation,
+or never.
 
-``control=True`` is the control of the correctness check: the same
-search over a register that lets a read return any value the register
-has held, the guarantee etcd gives without quorum reads.  It is not
-linearizability, and the check has to tell the two apart.
+The model is its file, `models/<model>.py` (`spec.model`):
+``start(init, control)`` is its first state and ``step(state, op,
+control)`` its transition, None where it does not allow the op.
+``control=True`` is one control of the correctness check: the same
+search over the model's weaker guarantee (for the CAS register, a read
+may return any value the register has held, as etcd gives without
+quorum reads).  It is not linearizability, and the check has to tell
+the two apart.
 """
 
 from __future__ import annotations
@@ -36,22 +39,7 @@ def split_keys(events):
     return out
 
 
-def _step(state, op, control):
-    val, held = state
-    f, v = op["f"], op["value"]
-    if f == "read":
-        ok = v is None or v == val or (control and v in held)
-        return state if ok else None
-    if f == "write":
-        new = v
-    elif val == v[0]:
-        new = v[1]
-    else:
-        return None
-    return (new, held | {new}) if control else (new, held)
-
-
-def check(rows, init, *, control=False):
+def check(model, rows, init, *, control=False):
     """Returns (valid, configurations visited, depth): depth is the most
     rows (ok and info) any configuration linearized, the length of
     knossos's deepest partial linearizations.  The set of linearized
@@ -61,8 +49,7 @@ def check(rows, init, *, control=False):
     oks = [op for op in rows if op["type"] == "ok"]
     infos = [op for op in rows if op["type"] != "ok"]
     n = len(oks)
-    held = frozenset([init]) if control else frozenset()
-    start = (0, 0, 0, (init, held))
+    start = (0, 0, 0, model.start(init, control))
     seen = {start}
     stack = [start]
     depth = 0
@@ -78,7 +65,7 @@ def check(rows, init, *, control=False):
         while j < n and oks[j]["inv"] < first_ret:
             if not rel >> (j - low) & 1:
                 first_ret = min(first_ret, oks[j]["ret"])
-                nxt = _step(state, oks[j], control)
+                nxt = model.step(state, oks[j], control)
                 if nxt is not None:
                     r = rel | 1 << (j - low)
                     t = (~r & (r + 1)).bit_length() - 1
@@ -89,7 +76,7 @@ def check(rows, init, *, control=False):
             j += 1
         for i, op in enumerate(infos):
             if op["inv"] < first_ret and not done_info >> i & 1:
-                nxt = _step(state, op, control)
+                nxt = model.step(state, op, control)
                 if nxt is not None:
                     cfg = (low, rel, done_info | 1 << i, nxt)
                     if cfg not in seen:
@@ -98,10 +85,10 @@ def check(rows, init, *, control=False):
     return False, len(seen), depth
 
 
-def replay(rows, order, init, *, whole=True):
+def replay(model, rows, order, init, *, whole=True):
     """True iff ``order`` (row indices) is a linearization: distinct
     rows, each invoked before every other row not yet placed returned,
-    and allowed by the register; with ``whole``, every ok row placed.
+    and allowed by the model; with ``whole``, every ok row placed.
     With ``whole=False`` it checks a partial linearization, as knossos
     reports the deepest ones of an invalid history."""
     if len(set(order)) != len(order) or any(
@@ -112,14 +99,14 @@ def replay(rows, order, init, *, whole=True):
         return False
     # returns of the ok rows not yet placed, ascending
     open_rets = sorted((rows[i]["ret"], i) for i in oks)
-    state = (init, frozenset())
+    state = model.start(init, False)
     for i in order:
         first = next((r for r, j in open_rets[:2] if j != i), INF)
         if rows[i]["inv"] > first:
             return False
         if i in oks:
             open_rets.remove((rows[i]["ret"], i))
-        state = _step(state, rows[i], False)
+        state = model.step(state, rows[i], False)
         if state is None:
             return False
     return True

@@ -1,8 +1,9 @@
 """`BENCHMARK.json` and the files it names, found by name: a cell's
-configuration file, its traffic mix `traffic/<traffic>.json`, the chip
-peaks `peaks.json`, and each per-layer metric's reader
-`metrics/<metric>.py`.  Adding a cell, a mix or a metric is adding
-files and an entry; nothing here changes."""
+configuration file, its traffic mix `traffic/<traffic>.json`, the model
+the configuration's checker names `models/<model>.py`, the chip peaks
+`peaks.json`, and each per-layer metric's reader `metrics/<metric>.py`.
+Adding a cell, a mix, a model or a metric is adding files and an entry;
+nothing here changes."""
 
 from __future__ import annotations
 
@@ -41,16 +42,35 @@ def metrics(spec, name, traced):
     return [m for m in group if name in m.get("workloads", [name])]
 
 
-def reader(metric, root=ROOT):
-    """The `read(ctx)` function of a per-layer metric's own file."""
+def _load(kind, name, root):
+    """The module of ``<kind>/<name>.py`` under the benchmark, by file
+    location; a missing file is a KeyError that names it."""
     bench = os.path.join(root, os.path.basename(BENCH))
-    path = os.path.join(bench, "metrics", metric["name"] + ".py")
+    path = os.path.join(bench, kind, name + ".py")
+    if not os.path.isfile(path):
+        raise KeyError(f"no file {os.path.relpath(path, root)} for "
+                       f"{kind} {name!r}")
     sp = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric["name"].replace(".", "_")
-        .replace("-", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(sp)
     sp.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric, root=ROOT):
+    """The `read(ctx)` function of a per-layer metric's own file."""
+    return _load("metrics", metric["name"], root).read
+
+
+def model(config, root=ROOT):
+    """The module of the model a configuration's checker names (its
+    ``checker.model``, the program's model, which `core/sut.py`
+    builds): its generator, corruption, reference step and control, and
+    the bits of its state (`models/cas_register.py` shows each).  One
+    name picks both sides, so the reference cannot check another model
+    than the program's.  A configuration that names none, or a model
+    with no file, is an error, not a default."""
+    return _load("models", config["checker"]["model"], root)
 
 
 def peaks(kind, root=ROOT):

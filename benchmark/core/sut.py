@@ -14,15 +14,17 @@ if ROOT not in sys.path:
 
 def build(config):
     """The checker a suite builds for this deployment: the one-line
-    `Linearizable(cas_register(...), algorithm=...)` swap, every other
-    knob at its library default; wrapped in `independent.checker` for a
-    test over independent keys."""
-    from jepsen_tpu import independent
+    `Linearizable(<model>(...), algorithm=...)` swap, the program's
+    model named by the configuration's ``checker`` entry and given the
+    configuration's initial value (``init``, which every configuration
+    states; null is the model's own default), every other knob at its
+    library default; wrapped in `independent.checker` for a test over
+    independent keys."""
+    from jepsen_tpu import independent, models
     from jepsen_tpu.checker.linearizable import Linearizable
-    from jepsen_tpu.models import cas_register
 
-    model = cas_register() if config["init"] is None \
-        else cas_register(config["init"])
+    make = getattr(models, config["checker"]["model"])
+    model = make() if config["init"] is None else make(config["init"])
     chk = Linearizable(model, algorithm=config["checker"]["algorithm"])
     if config["layout"] == "independent":
         chk = independent.checker(chk)

@@ -22,15 +22,14 @@ def read(ctx):
         if len(rows) != 1:
             return None   # per-level spans are the solo route's
         (rows,) = rows.values()
-        n_values = ctx["values"]
-        cfg = config_bytes(rows, n_values)
+        cfg = config_bytes(rows, ctx["state_bits"])
         for s in ctx["spans"]:
             if c0 <= s["t0"] < c1 and t0 <= s["t0"] < t1:
                 if s["name"] == "device.level":
                     total += cfg * (s["args"].get("occupancy", 0)
                                     + s["args"].get("next_count", 0))
                 elif s["name"] == "device.slice":
-                    total += table_bytes(rows, n_values)
+                    total += table_bytes(rows, ctx["state_bits"])
     if not total:
         return None
     peak = spec.peaks(ctx["device_kind"])["hbm_bytes_per_s"]

@@ -94,13 +94,17 @@ def test_phase_metric_entry(name):
     (m,) = [m for m in spec.load()["per_layer"] if m["name"] == name]
     assert (m["source"], m["layer"], m["better"], m["moves"]) == (
         "program_span", "entry points", "lower", "verified_ops_per_s")
-    # the independent-keys phases exist only in the etcdemo cell
-    want = ["etcdemo-stale"] if name in ("split_share", "recheck_share") \
-        else ["etcdemo-stale", "zk1k-stale"]
+    # the independent-keys phases exist only in the etcdemo cells, and
+    # the phases of an invalid key's re-check only where keys are invalid
+    want = {"split_share": ["etcdemo-stale", "etcdemo-clean"],
+            "recheck_share": ["etcdemo-stale"],
+            "encode_share": ["etcdemo-stale", "zk1k-stale", "etcdemo-clean"]
+            }.get(name, ["etcdemo-stale", "zk1k-stale"])
     assert m["workloads"] == want
 
 
-@pytest.mark.parametrize("cell", ["etcdemo-stale", "zk1k-stale"])
+@pytest.mark.parametrize("cell", ["etcdemo-stale", "zk1k-stale",
+                                  "etcdemo-clean"])
 def test_a_traced_run_reports_every_phase_metric_of_its_cell(cell):
     r = small_run(cell, trace=1)
     assert r["correct"], r["checks"]

@@ -53,7 +53,9 @@ Histories whose window or crash count exceed the device encoding fall
 back to the exact `linear` host sweep (checker/linear.py);
 Linearizable.check additionally re-runs short failing prefixes
 (≤ witness_threshold ops) on the WGL host oracle (checker/seq.py) to
-reconstruct a human-readable witness, and `check_competition` races
+reconstruct a human-readable witness, certifies a valid device verdict
+on a history of at most witness_threshold ops with that oracle's
+linearization, and `check_competition` races
 both host engines against the device search outright (the knossos
 `competition` analog).
 
@@ -2541,6 +2543,15 @@ def search_opseq(seq: OpSeq, model: ModelSpec, *,
     return finish(out)
 
 
+def host_config_cap(rows: int, max_configs: int = 5_000_000) -> int:
+    """``max_configs`` capped to a ~4 GB host DFS footprint: the DFS
+    memoizes each config TWICE (visited + parent_of) as a (bigint
+    linearized-set, state tuple) pair, ~rows/8 bytes of mask plus a
+    couple hundred bytes of object overhead per copy."""
+    per_cfg = 2 * (rows // 8 + 200)
+    return min(max_configs, 4_000_000_000 // per_cfg)
+
+
 def check_competition(seq: OpSeq, model: ModelSpec, *,
                       budget: int = 20_000_000,
                       max_configs: int = 50_000_000,
@@ -2584,15 +2595,10 @@ def check_competition(seq: OpSeq, model: ModelSpec, *,
     def finish(out: dict) -> dict:
         return maybe_audit(seq, model, out, audit)
 
-    # the host DFS memoizes each config TWICE (visited + parent_of) as a
-    # (bigint linearized-set, state tuple) pair: ~n/8 bytes of mask plus
-    # a couple hundred bytes of object overhead per copy.  Cap its
-    # configs to a ~4 GB footprint so the loser thread cannot eat the
-    # machine while the device grinds a long history (the reference
-    # answers this with -Xmx32g; we'd rather lose the race than the
-    # host).
-    per_cfg = 2 * (len(seq) // 8 + 200)
-    max_configs = min(max_configs, 4_000_000_000 // per_cfg)
+    # the loser thread must not eat the machine while the device grinds
+    # a long history (the reference answers this with -Xmx32g; we'd
+    # rather lose the race than the host)
+    max_configs = host_config_cap(len(seq), max_configs)
 
     done = threading.Event()
     lock = threading.Lock()
@@ -4033,7 +4039,10 @@ class Linearizable:
       * larger histories run the device search;
       * an invalid device verdict is re-verified (and a witness frontier
         extracted) by the host oracle when the history is small enough to
-        afford it, closing the fingerprint-collision soundness hole.
+        afford it, closing the fingerprint-collision soundness hole;
+      * a valid device verdict, which carries no linearization, gets the
+        host oracle's as its certificate under the same size limit; a
+        host that disagrees makes the verdict "unknown".
 
     ``model`` may be given at construction or ride in test["model"].
     """
@@ -4258,6 +4267,7 @@ class Linearizable:
                 self._render_failure(test, seq, out, opts, model)
             return out
 
+        t0 = time.perf_counter()
         if self.algorithm in ("auto", "competition"):
             # the reference's default is :competition
             # (checker.clj:122-126): race the exact host DFS against the
@@ -4271,6 +4281,7 @@ class Linearizable:
             out = search_opseq(seq, model, budget=self.budget,
                                lint=False, hb=self.hb,
                                dpor=self.dpor)
+        search_s = time.perf_counter() - t0
         if out["valid"] is False:
             eng = out.get("engine", "")
             if "host-oracle" in eng or "host-linear" in eng:
@@ -4281,27 +4292,74 @@ class Linearizable:
                 return out
             # exact confirmation + witness for the report, on the
             # shortest sound prefix covering the failure region
-            confirm = None
             with obs.span("linearizable.confirm") as sp:
                 target = seq
                 trunc = truncate_to_failure(seq, out.get("max_depth", 0),
                                             out.get("window", 1))
                 if trunc is not None:
                     target = trunc
-                if len(target) <= self.witness_threshold:
-                    confirm = seqmod.check_opseq(target, model,
-                                                 lint=False)
-                    sp.set(configs=confirm.get("configs"),
-                           valid=confirm["valid"])
+                confirm = self._host_recheck(target, model, out, sp)
                 sp.set(prefix_ops=len(target))
             if confirm is not None and confirm["valid"] is False:
-                confirm["engine"] = out["engine"] + "+host-witness"
                 confirm["device_configs"] = out["configs"]
                 confirm["witness_prefix_ops"] = len(target)
                 self._render_failure(test, target, confirm, opts, model)
                 return confirm
             # no confirmation, or the prefix came back valid: the full
             # device verdict stands (obstruction lies past the cut)
+        elif (out["valid"] is True and out.get("linearization") is None
+              and len(seq) <= self.witness_threshold):
+            return self._certify(seq, model, out, search_s)
+        return out
+
+    #: a valid verdict's certification may take this share of the
+    #: device search's own wall time, so it at most doubles the
+    #: verdict's latency; after a device win of the competition, the
+    #: host DFS that lost the race runs again for at most as long
+    CERTIFY_TIME_SHARE = 1.0
+
+    def _host_recheck(self, seq, model, out, sp, **bounds):
+        """The exact host search (`seq.check_opseq`) over ``seq`` behind
+        the device verdict ``out``, under the open span ``sp`` (which
+        gets ``configs`` and ``valid``): the host's result, engine
+        ``<device engine>+host-witness``, or None past
+        ``witness_threshold`` rows."""
+        from . import seq as seqmod
+
+        if len(seq) > self.witness_threshold:
+            return None
+        host = seqmod.check_opseq(seq, model, lint=False, hb=self.hb,
+                                  dpor=self.dpor, **bounds)
+        sp.set(configs=host.get("configs"), valid=host["valid"])
+        host["engine"] = out["engine"] + "+host-witness"
+        return host
+
+    def _certify(self, seq, model, out, search_s):
+        """A valid device verdict's certificate: the device BFS keeps no
+        parent chains, so the host search re-checks the history and its
+        linearization becomes the witness.  The host search is bounded
+        by the competition's memory cap and by ``CERTIFY_TIME_SHARE`` of
+        the device search's ``search_s`` seconds.  A host that answers
+        "invalid" contradicts the device, and the verdict is "unknown"
+        with both answers; a host that gives up leaves the device
+        verdict as it was, uncertified."""
+        with obs.span("linearizable.certify", rows=len(seq)) as sp:
+            host = self._host_recheck(
+                seq, model, out, sp,
+                max_configs=host_config_cap(len(seq)),
+                deadline=(time.perf_counter()
+                          + self.CERTIFY_TIME_SHARE * search_s))
+        if host["valid"] is True:
+            out = {k: v for k, v in out.items() if k != "witness_dropped"}
+            out.update(linearization=host["linearization"],
+                       engine=host["engine"],
+                       certify_configs=host.get("configs"))
+            return out
+        if host["valid"] is False:
+            return {"valid": "unknown", "engine": out["engine"],
+                    "error": "the device search and the host search "
+                             "disagree",
+                    "device": out, "host": host}
         return out
 
     #: don't delta-debug failure reports past this many rows — each

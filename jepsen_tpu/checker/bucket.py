@@ -177,15 +177,20 @@ def search_batch_bucketed(seqs: list[OpSeq], model: ModelSpec, *,
     n = len(seqs)
     t_start = time.perf_counter()
     kc0 = lin.kernel_cache_stats()
-    ess = [lin.encode_search(s) for s in seqs]
+    # every key's search encoding, then the buckets: host work ahead of
+    # the first bucket's prep
+    with obs.span("bucket.plan", cat="host", keys=n) as sp:
+        ess = [lin.encode_search(s) for s in seqs]
+        hard, fit = [], []
+        for i, e in enumerate(ess):
+            (hard if e.window > lin.MAX_WINDOW
+             or e.n_crash > lin.MAX_CRASH else fit).append(i)
+        _enabled, max_buckets = _bucket_mode()
+        plans = plan_buckets([bucket_key(ess[i]) for i in fit],
+                             max_buckets)
+        plans = [[fit[p] for p in grp] for grp in plans]
+        sp.set(buckets=len(plans))
     results: list = [None] * n
-    hard, fit = [], []
-    for i, e in enumerate(ess):
-        (hard if e.window > lin.MAX_WINDOW
-         or e.n_crash > lin.MAX_CRASH else fit).append(i)
-    _enabled, max_buckets = _bucket_mode()
-    plans = plan_buckets([bucket_key(ess[i]) for i in fit], max_buckets)
-    plans = [[fit[p] for p in grp] for grp in plans]
 
     stats: dict = {"n_keys": n, "n_buckets": len(plans), "buckets": [],
                    "greedy": 0, "hard": len(hard), "hb_decided": 0,

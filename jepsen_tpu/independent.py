@@ -84,6 +84,45 @@ def subhistory(k, history: Iterable[Op]) -> list[Op]:
     return out
 
 
+def _unwrap(op: Op) -> Op:
+    """`op` with its KV value replaced by the KV's value."""
+    if type(op) is Op:
+        return Op(op.process, op.type, op.f, op.value.value, op.time,
+                  op.index, op.error)
+    from dataclasses import replace
+
+    return replace(op, value=op.value.value)
+
+
+def subhistories(history: Iterable[Op]) -> dict:
+    """Every key's subhistory in one pass: ``{k: subhistory(k, history)
+    for k in history_keys(history)}``, keys in first-appearance order."""
+    return _split(history)[0]
+
+
+def _split(history: Iterable[Op]) -> tuple[dict, int]:
+    """`subhistories`, and the number of un-keyed ops.
+
+    A key seen for the first time starts from the un-keyed ops before it;
+    an un-keyed op is appended, the same object, to every key seen so
+    far.  O(events + unkeyed x keys), where the per-key rescan is
+    O(keys x events)."""
+    unkeyed: list[Op] = []
+    subs: dict = {}
+    for op in history:
+        v = op.value
+        if isinstance(v, KV):
+            sub = subs.get(v.key)
+            if sub is None:
+                sub = subs[v.key] = unkeyed.copy()
+            sub.append(_unwrap(op))
+        else:
+            unkeyed.append(op)
+            for sub in subs.values():
+                sub.append(op)
+    return subs, len(unkeyed)
+
+
 class SequentialGenerator:
     """Work through keys one at a time (independent.clj:31-64): build
     fgen(k1), emit its ops (values wrapped as [k1 v]) until exhausted,
@@ -264,9 +303,9 @@ class IndependentChecker(Checker):
         from .checker.linearizable import Linearizable
 
         with obs.span("independent.split", events=len(history)) as sp:
-            ks = history_keys(history)
-            subs = {k: subhistory(k, history) for k in ks}
-            sp.set(keys=len(ks))
+            subs, unkeyed = _split(history)
+            ks = list(subs)
+            sp.set(keys=len(ks), unkeyed=unkeyed)
         if self.batch_device and isinstance(self.checker, Linearizable):
             results = self._device_batch(test, subs)
         else:

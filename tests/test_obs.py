@@ -792,9 +792,14 @@ def test_independent_checker_records_split_encode_key(tracing, tmp_path):
     for s in spans:
         by.setdefault(s["name"], []).append(s)
     (split,) = by["independent.split"]
-    assert split["args"] == {"events": len(h), "keys": 3}
+    assert split["args"] == {"events": len(h), "keys": 3, "unkeyed": 0}
     (enc,) = by["independent.encode"]
     assert enc["args"] == {"keys": 3}
+    # the batch's plan: keys 0 and 1 ride it, key 2 is checked alone
+    (plan,) = by["bucket.plan"]
+    assert plan["args"]["keys"] == 2 and plan["args"]["buckets"] >= 1
+    assert plan["ts"] + plan["dur"] <= min(s["ts"]
+                                           for s in by["bucket.prep"])
     keys = {s["args"]["key"]: s["args"] for s in by["independent.key"]}
     assert keys[0]["why"] == "recheck" and keys[2]["why"] == "small"
     assert 1 not in keys

@@ -18,7 +18,8 @@ Phases (one chip):
 * device   — jax.devices()[0] is a TPU.
 * suite    — the atomdemo suite in process, linearizable checker on
   algorithm="device"; results.json must be valid with device engines.
-* 1k, mutex2k — bench.py's seeded single histories through
+* 1k, mutex2k — BASELINE.md's seeded single histories
+  (``synth.baseline_history``) through
   ``Linearizable(model, algorithm="device", hb=False)`` (audited
   certificates; the static prepass would decide mutex2k with no
   search at all, so it is off wherever the device must run), with
@@ -26,8 +27,8 @@ Phases (one chip):
   with the Pallas kernel wherever it is eligible; every verdict must
   equal host ``linear``.  Where a selector picked the Pallas kernel,
   the engine label must say so.
-* batch    — ``search_batch`` over bench.py's 256 keys (BASELINE
-  config 3), each verdict compared with the host.
+* batch    — ``search_batch`` over BASELINE config 3's 256 keys
+  (``synth.baseline_batch``), each verdict compared with the host.
 * stream   — a few runs of op lines through the in-process stream
   service with every fold routed to the device; each final verdict
   equals the post-hoc one and carries ``fallback: false``.
@@ -144,12 +145,12 @@ def phase_suite(out_dir):
 
 
 def phase_single(name):
-    """One bench history through Linearizable(algorithm="device"),
+    """One BASELINE history through Linearizable(algorithm="device"),
     auto-selected engine and pinned XLA, against host linear."""
-    import bench
+    from jepsen_tpu import synth
     from jepsen_tpu.checker import linearizable as lin
 
-    seq, model = bench.make_seq(name)
+    seq, model = synth.baseline_history(name)
     t0 = time.perf_counter()
     want = _host(seq, model)
     t_host = time.perf_counter() - t0
@@ -199,10 +200,10 @@ def phase_single(name):
 
 
 def _batch(sharding=None):
-    import bench
+    from jepsen_tpu import synth
     from jepsen_tpu.checker import linearizable as lin
 
-    seqs, model = bench.make_batch()
+    seqs, model = synth.baseline_batch()
     t0 = time.perf_counter()
     res = lin.search_batch(seqs, model, sharding=sharding, audit=True)
     dt = time.perf_counter() - t0
@@ -279,10 +280,10 @@ def phase_stream():
 
 
 def phase_10k():
-    import bench
+    from jepsen_tpu import synth
     from jepsen_tpu.checker import linearizable as lin
 
-    seq, model = bench.make_seq("10k")
+    seq, model = synth.baseline_history("10k")
     t0 = time.perf_counter()
     want = _host(seq, model)
     t_host = time.perf_counter() - t0
@@ -306,7 +307,7 @@ def phase_mesh(devices):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    import bench
+    from jepsen_tpu import synth
     from jepsen_tpu.checker import linearizable as lin
 
     mesh = Mesh(np.array(devices), ("shard",))
@@ -328,7 +329,7 @@ def phase_mesh(devices):
     _check(sorted(dev_keys) == sorted(str(d.id) for d in devices)
            and all(v > 0 for v in dev_keys.values()),
            f"mesh batch did not place keys on every device: {dev_keys}")
-    seq, model = bench.make_seq("1k")
+    seq, model = synth.baseline_history("1k")
     want1 = _host(seq, model)
     t0 = time.perf_counter()
     out = lin.search_opseq_sharded(seq, model, mesh, audit=True)

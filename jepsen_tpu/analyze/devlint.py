@@ -1,17 +1,15 @@
 """Device-contract lint — K-codes over staged kernel jaxprs.
 
-The bench contract (PR 10 onward, executable since PR 15) catches
-recompiles, mid-search host transfers, and cache-key drift *after* a
-bench run regresses.  This module is the static counterpart: it
-abstractly stages every kernel route the checker can dispatch —
+Recompiles, mid-search host transfers and cache-key drift would
+otherwise show only as a regressed benchmark number.  This module
+catches them statically: it abstractly stages every kernel route the checker can dispatch —
 single-device XLA, bucketed batch, mesh-sharded, pallas fused,
 enumerated from :data:`jepsen_tpu.checker.linearizable.KERNEL_ROUTES`
 — over representative :class:`SearchDims`, then walks the resulting
 jaxprs for the device-contract violations the runtime gates would only
 see as a regressed number.  ``jax.make_jaxpr`` traces without
 compiling, so the whole sweep is a few seconds on CPU and runs in
-tier-1 (tests/test_devlint.py) and as a ``bench.py --trace``
-preflight.
+tier-1 (tests/test_devlint.py).
 
 K-code reference (docs/analyze.md has the prose version):
 
@@ -30,10 +28,9 @@ K-code reference (docs/analyze.md has the prose version):
   K006  effectful host round-trip (debug prints, ordered callbacks)
         inside the scan body — a device→host transfer per level
   K007  compile-span cache-key coords missing or drifted versus the
-        static model below — ``fleet/warmup.py`` warm-boot and the
-        committed ``BENCH_trace_*.json`` recordings round-trip kernels
-        through exactly these coords, so drift means silent zero-miss
-        -verify failures
+        static model below — ``fleet/warmup.py`` warm-boot rebuilds
+        kernels from a recorded trace through exactly these coords,
+        so drift means silent zero-miss-verify failures
 
 Suppression: the staged checks (K001/K002/K003/K006) attribute
 findings to source lines via the jaxpr's ``source_info``; a
@@ -43,9 +40,8 @@ AST-level and honours the comment on the ``jax.jit`` call line.
 Suppressions are for *documented* false positives only.
 
 Wired into: ``python -m jepsen_tpu.analyze --devlint`` (CLI),
-``tools/lint_suites.py --json`` (suite sweep), ``tools/obs_guard.py``
-(K007 over committed trace compile spans), and ``bench.py --trace``
-preflight.
+``tools/lint_suites.py --json`` (suite sweep), and the warm-boot
+loaders of ``fleet/warmup.py`` (K007 over a trace's compile spans).
 """
 
 from __future__ import annotations
@@ -94,9 +90,12 @@ BASE_COORDS = frozenset({
     "model", "model_init", "model_width",
 })
 
-#: attrs ``obs/telemetry.compile_span`` itself adds — runtime facts,
-#: not cache-key coords, so excluded from the model comparison
-RUNTIME_COORDS = frozenset({"cache", "persistent_cache"})
+#: attrs ``obs/telemetry.compile_span`` itself adds, and the span
+#: identity ``obs.write_trace`` stamps on every exported event
+#: (``id``/``parent``/``check``) — runtime facts, not cache-key coords,
+#: so excluded from the model comparison
+RUNTIME_COORDS = frozenset({"cache", "persistent_cache",
+                            "id", "parent", "check"})
 
 #: span_kind -> required coord set, newest generation.  span_kind is
 #: declared per route (KernelRoute.span_kind) and recoverable from a
@@ -108,23 +107,10 @@ CACHE_KEY_MODEL = {
     "window-sharded": BASE_COORDS | {"shards"},
 }
 
-#: coord sets earlier PRs emitted, oldest first — committed
-#: ``BENCH_trace_*.json`` recordings predating the full model are
-#: validated against these; LIVE staging (and any trace recorded from
-#: now on) must match the newest generation exactly
-LEGACY_GENERATIONS = (
-    # PR 15: first span accounting — engine + two dims only
-    frozenset({"engine", "frontier", "n_det_pad"}),
-    # PR 16 fleet tier: warm-boot needed window/k/crash pad
-    frozenset({"engine", "frontier", "n_det_pad", "n_crash_pad",
-               "window", "k"}),
-)
-
 
 def span_kind_for_args(args: dict) -> str:
     """Classify a recorded ``device.compile`` span into the coord
-    model's span_kind.  Legacy spans missing the batch/sharded markers
-    classify as solo — their generation check still passes."""
+    model's span_kind."""
     if args.get("engine") == "device-sharded":
         return "window-sharded"
     if "sharded" in args or args.get("shards") is not None:
@@ -171,16 +157,13 @@ def _coord_domain_errors(args: dict) -> list[str]:
     return errs
 
 
-def check_span_args(args: dict, *, kind: str | None = None,
-                    strict: bool = True) -> list[str]:
+def check_span_args(args: dict, *,
+                    kind: str | None = None) -> list[str]:
     """K007 core: validate one ``device.compile`` span's args against
-    the static cache-key model.
-
-    ``strict=True`` (live staging, bench preflight, newly recorded
-    traces): the coord key set must equal the newest generation for
-    its span_kind.  ``strict=False`` (committed historical traces): a
-    legacy generation's key set is also accepted.  Returns a list of
-    failure strings, empty when clean."""
+    the static cache-key model: the coord key set must equal
+    :data:`CACHE_KEY_MODEL`'s for its span_kind, and every coord must
+    hold a possible value.  Returns a list of failure strings, empty
+    when clean."""
     keys = frozenset(args) - RUNTIME_COORDS
     if kind is None:
         kind = span_kind_for_args(args)
@@ -189,16 +172,14 @@ def check_span_args(args: dict, *, kind: str | None = None,
         return [f"unknown span_kind {kind!r}"]
     failures = []
     if keys != required:
-        legacy_ok = (not strict) and keys in LEGACY_GENERATIONS
-        if not legacy_ok:
-            missing = sorted(required - keys)
-            extra = sorted(keys - required)
-            parts = []
-            if missing:
-                parts.append(f"missing coords {missing}")
-            if extra:
-                parts.append(f"unmodelled coords {extra}")
-            failures.append(f"[{kind}] " + ", ".join(parts))
+        missing = sorted(required - keys)
+        extra = sorted(keys - required)
+        parts = []
+        if missing:
+            parts.append(f"missing coords {missing}")
+        if extra:
+            parts.append(f"unmodelled coords {extra}")
+        failures.append(f"[{kind}] " + ", ".join(parts))
     failures.extend(_coord_domain_errors(args))
     return failures
 
@@ -482,12 +463,12 @@ def capture_compile_spans(route, model=None, dims=None) -> list[dict]:
 
 
 def lint_compile_spans(route, spans: list[dict]) -> list[Diagnostic]:
-    """K007 over live-captured spans: strict (newest-generation)
-    coord check against the route's declared span_kind."""
+    """K007 over live-captured spans, checked against the route's
+    declared span_kind."""
     diags = []
     for s in spans:
         for fail in check_span_args(s.get("args", {}),
-                                    kind=route.span_kind, strict=True):
+                                    kind=route.span_kind):
             diags.append(Diagnostic(
                 "K007", "error",
                 f"{route.name}: device.compile span coords drift vs "
@@ -498,19 +479,19 @@ def lint_compile_spans(route, spans: list[dict]) -> list[Diagnostic]:
 
 def lint_trace_spans(trace_obj: dict, *, name: str = "<trace>"
                      ) -> list[Diagnostic]:
-    """K007 over one committed Chrome-trace JSON object
-    (``BENCH_trace_*.json``): every ``device.compile`` event's args
-    must match the static model, legacy generations allowed.  Traces
-    with no compile spans pass vacuously (a fully warm recording)."""
+    """K007 over one recorded Chrome-trace JSON object
+    (``obs.write_trace``): every ``device.compile`` event's args must
+    match the static model.  Traces with no compile spans pass
+    vacuously (a fully warm recording)."""
     diags = []
     for ev in trace_obj.get("traceEvents", ()):
         if ev.get("name") != "device.compile":
             continue
         args = ev.get("args", {}) or {}
-        for fail in check_span_args(args, strict=False):
+        for fail in check_span_args(args):
             diags.append(Diagnostic(
                 "K007", "error",
-                f"{name}: committed compile span drifts vs the static "
+                f"{name}: recorded compile span drifts vs the static "
                 f"cache-key model: {fail}"))
     return diags
 

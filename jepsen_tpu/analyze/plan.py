@@ -398,7 +398,7 @@ def _telemetry_block(engine: str) -> dict:
     observed twin of the hb/dpor predicted prune ratios will land.
     Plans are predictions; a run of the predicted engine attaches the
     measured side, and the two are diffed everywhere downstream
-    (result block, trace_report, obs_guard)."""
+    (result block, trace_report)."""
     from ..obs import telemetry as tele
 
     on = tele.enabled()

@@ -102,10 +102,12 @@ observability surfaces must agree on which series exist):
 
 ==== ======== ==========================================================
 O001 error    a ``jtpu_*`` series referenced by a consumer surface
-              (``web.py``, ``tools/obs_guard.py``,
-              ``obs_thresholds.json``) is registered nowhere in the
-              package — the dashboard panel / guard threshold gates on
-              a series that can never report
+              (``web.py``, the benchmark's metric readers under
+              ``benchmark/metrics/``, ``benchmark/core/readers.py``
+              and the counter collector ``benchmark/core/sut.py``) is
+              registered nowhere in the package — the dashboard
+              panel / benchmark metric reads a series that can never
+              report
 O002 warning  registered series no consumer surface references
               (aggregated into one finding) — orphans are not wrong,
               but each one is either a missing dashboard panel or dead
@@ -1265,12 +1267,12 @@ def lint_knobs(pkg_root: str | Path | None = None,
 # O-codes — jtpu_* metrics contract (registration vs consumer surfaces)
 # ---------------------------------------------------------------------------
 
-#: consumer surfaces, relative to the REPO root (pkg_root.parent):
-#: the dashboard, the scrape guard, and the alert thresholds — a
-#: series one of these names must exist, or the panel/threshold gates
-#: on nothing
-METRIC_CONSUMERS = ("jepsen_tpu/web.py", "tools/obs_guard.py",
-                    "obs_thresholds.json")
+#: consumer surfaces, relative to the REPO root (pkg_root.parent): the
+#: dashboard and the benchmark's metric readers (a directory stands for
+#: its ``*.py`` files) — a series one of these names must exist, or the
+#: panel / metric reads nothing
+METRIC_CONSUMERS = ("jepsen_tpu/web.py", "benchmark/metrics",
+                    "benchmark/core/readers.py", "benchmark/core/sut.py")
 
 _METRIC_RE = re.compile(r"\bjtpu_[a-z0-9_]+\b")
 
@@ -1318,7 +1320,10 @@ def lint_metrics(pkg_root: str | Path | None = None,
     pkg = Path(pkg_root) if pkg_root else \
         Path(__file__).resolve().parent.parent
     if consumers is None:
-        consumers = [pkg.parent / c for c in METRIC_CONSUMERS]
+        consumers = []
+        for c in METRIC_CONSUMERS:
+            p = pkg.parent / c
+            consumers += sorted(p.glob("*.py")) if p.is_dir() else [p]
     registered = registered_metrics(pkg)
 
     def base_name(name: str) -> str:
@@ -1347,8 +1352,8 @@ def lint_metrics(pkg_root: str | Path | None = None,
                     "O001", "error",
                     f"{c}:{lineno}: {m.group(0)} is referenced here "
                     f"but registered nowhere in the package — the "
-                    f"panel/threshold gates on a series that can "
-                    f"never report", index=lineno))
+                    f"panel / metric reads a series that can never "
+                    f"report", index=lineno))
         if diags:
             out[str(c)] = diags
 
@@ -1360,7 +1365,7 @@ def lint_metrics(pkg_root: str | Path | None = None,
         out.setdefault(f0, []).append(Diagnostic(
             "O002", "warning",
             f"{len(orphans)} registered jtpu_* series no consumer "
-            f"surface (web.py / obs_guard / thresholds) references: "
+            f"surface (web.py / benchmark readers) references: "
             f"{shown}", index=l0))
     return out
 

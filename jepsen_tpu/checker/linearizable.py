@@ -2470,8 +2470,8 @@ def search_opseq(seq: OpSeq, model: ModelSpec, *,
 
     if hbres is not None and hbres.decided is not None:
         # statically decided: no device work, but the telemetry span
-        # still records observed=0 vs predicted=0 so traces (and
-        # obs_guard's prune-delta check) cover decided tiers too
+        # still records observed=0 vs predicted=0 so traces cover
+        # decided searches too
         return _tele.emit_decided(
             maybe_audit(seq, model, dict(hbres.decided), audit),
             hbres=hbres)
@@ -2723,8 +2723,8 @@ def save_checkpoint(path: str, carry, dims: SearchDims, model: ModelSpec,
     last).  The truth comes from the ACTIVE slice driver via a
     thread-local (`_run_kernel` maintains it, seeded with the resumed
     checkpoint's flag) — never from re-reading the target file, which
-    callers like bench.py write through a tmp-path + rename and which
-    would therefore never show the prior state."""
+    a caller may write through a tmp-path + rename and which would
+    therefore never show the prior state."""
     c = [np.asarray(x) for x in carry]
     digest = history_digest(seq, model) if seq is not None else ""
     used_p = getattr(_RUN_PALLAS, "flag", None)

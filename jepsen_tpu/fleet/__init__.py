@@ -10,10 +10,8 @@ admission (``warmup.py``), and an admission controller turns shed
 rate / open runs / fold backlog into accept / shed / spawn-worker
 decisions (``admission.py``).
 
-``python -m jepsen_tpu.fleet`` wires the pieces into a running tier;
-``stream/bench.py --fleet-tier`` drives a synthetic client swarm
-against it and records the throughput knee (BENCH_fleet.json).  See
-docs/fleet.md for the walkthrough.
+``python -m jepsen_tpu.fleet`` wires the pieces into a running tier.
+See docs/fleet.md for the walkthrough.
 """
 
 from .admission import AdmissionController, AdmissionPolicy  # noqa: F401

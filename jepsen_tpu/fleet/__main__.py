@@ -130,7 +130,7 @@ def main(argv=None) -> int:
                         "dead-worker salvage source.  Default: "
                         "<cache-root>/persist.")
     p.add_argument("--warmup", metavar="MANIFEST", default=None,
-                   help="Warm-boot manifest or BENCH_trace_*.json "
+                   help="Warm-boot manifest or recorded trace JSON "
                         "handed to every worker; admission requires "
                         "a verified report.")
     p.add_argument("--model", default=None,

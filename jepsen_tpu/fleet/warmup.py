@@ -5,8 +5,8 @@ A cold ``stream.service`` worker pays the 1.4-2.4s-per-kernel XLA
 compile tax on its first runs — exactly the runs the router just
 routed at it because it looked healthy.  The warm-boot gate inverts
 that: at worker start, :func:`warm_boot` compiles every kernel shape
-the steady state needs (shapes read from a recorded
-``BENCH_trace_*.json``'s ``device.compile`` spans, or from an explicit
+the steady state needs (shapes read from the ``device.compile`` spans
+of a trace recorded with ``obs.write_trace``, or from an explicit
 shape manifest) and **verifies** the warmth by re-requesting each
 kernel and asserting a zero miss delta on
 ``checker.linearizable.KERNEL_CACHE_STATS``.  Only a verified worker
@@ -31,17 +31,17 @@ Optional per-shape fields ``batch`` (total lane count — warms the
 vmapped batch kernel instead of the solo one) and ``shards`` (wraps it
 in ``shard_map`` over that many local devices: the bucketed mesh
 scheduler's steady-state shapes).  ``device.compile`` spans from
-sharded runs carry both, so a recorded ``BENCH_trace_shard.json``
+sharded runs carry both, so a trace recorded from a sharded run
 round-trips into exactly the kernel set the scheduler will request.
 
 Trace format: a telemetry trace (``{"traceEvents": [...]}``) whose
-``device.compile`` spans carry ``n_det_pad``/``frontier`` (always) and
-``window``/``n_crash_pad``/``k`` (newer traces); missing fields fall
-back to the steady-state defaults below.
+``device.compile`` spans carry the full cache-key coordinate set the
+kernel getters stamp (``engine``, the dims, the phase-2 flags and the
+model descriptor).
 
 Every loaded shape is validated against the **static cache-key model**
 (:func:`jepsen_tpu.analyze.devlint.check_span_args` — the same K007
-contract ``tools/obs_guard.py`` holds committed traces to).  A span or
+contract live kernel staging is held to).  A span or
 manifest entry whose coordinates drifted from the kernel cache key
 used to be *silently dropped or defaulted*, which surfaced much later
 as an unexplained zero-miss-verify failure (warm boot compiled the
@@ -58,8 +58,7 @@ import dataclasses
 import json
 import time
 
-#: steady-state defaults for trace spans predating the wider
-#: compile-span args (window/n_crash_pad/k)
+#: steady-state defaults for manifest entries that leave them out
 DEFAULT_WINDOW = 32
 DEFAULT_N_CRASH_PAD = 32
 DEFAULT_K = 4
@@ -137,7 +136,7 @@ def validate_shapes(shapes, *,
 
     good = []
     for i, s in enumerate(shapes):
-        errs = check_span_args(_shape_span_args(s), strict=True)
+        errs = check_span_args(_shape_span_args(s))
         if errs:
             _k007(diagnostics, f"warm shape #{i} ({s.model[0]})", errs)
             continue
@@ -171,16 +170,15 @@ def shapes_from_manifest(doc: dict, *,
 
 
 def shapes_from_trace(doc: dict, *,
-                      model: tuple = DEFAULT_MODEL,
                       diagnostics: list | None = None
                       ) -> list[WarmShape]:
     """The kernel shapes a recorded campaign actually compiled: every
     ``device.compile`` span in the trace, deduplicated.
 
-    Spans whose cache-key coordinates fail the static model (including
-    the pre-coordinate legacy spans the old loader skipped without a
-    word) are K007: raised, or reported-and-skipped when the caller
-    passes ``diagnostics``."""
+    A span whose cache-key coordinates fail the static model would
+    reconstruct a kernel the steady state never requests, so it is
+    K007: raised, or reported-and-skipped when the caller passes
+    ``diagnostics``."""
     from ..analyze.devlint import check_span_args
 
     out = []
@@ -191,15 +189,7 @@ def shapes_from_trace(doc: dict, *,
             continue
         args = ev.get("args", {}) or {}
         n_span += 1
-        # K007 gate: accept any documented cache-key generation (the
-        # committed bench traces span several), but a span that fits
-        # NO generation would reconstruct a kernel the steady state
-        # never requests — report it, don't silently default it.
-        # Trace spans predating the engine coordinate warmed the XLA
-        # route; that default loses nothing (engine is not a dim).
-        qargs = dict(args)
-        qargs.setdefault("engine", "xla")
-        errs = check_span_args(qargs, strict=False)
+        errs = check_span_args(args)
         if errs:
             _k007(diagnostics, f"device.compile span #{n_span}", errs)
             continue
@@ -207,26 +197,18 @@ def shapes_from_trace(doc: dict, *,
         # batch kernel getter wants the total lane axis back
         shards = int(args.get("shards", 0) or 0)
         batch = int(args.get("batch", 0) or 0)
-        # spans stamped with the model descriptor reconstruct against
-        # the model that actually compiled; older spans fall back to
-        # the caller-supplied default
-        mdl = tuple(model)
-        if "model" in args:
-            mdl = (str(args["model"]),
-                   int(args.get("model_init", 0)),
-                   int(args.get("model_width", 1)))
         s = WarmShape(
-            model=mdl,
+            model=(str(args["model"]), int(args["model_init"]),
+                   int(args["model_width"])),
             n_det_pad=int(args["n_det_pad"]),
-            n_crash_pad=int(args.get("n_crash_pad",
-                                     DEFAULT_N_CRASH_PAD)),
-            window=int(args.get("window", DEFAULT_WINDOW)),
-            k=int(args.get("k", DEFAULT_K)),
-            frontier=int(args.get("frontier", DEFAULT_FRONTIER)),
-            masked=bool(args.get("masked", False)),
-            masked_crash=bool(args.get("masked_crash", False)),
-            dedup=bool(args.get("dedup", False)),
-            vt=int(args.get("vt", 8)),
+            n_crash_pad=int(args["n_crash_pad"]),
+            window=int(args["window"]),
+            k=int(args["k"]),
+            frontier=int(args["frontier"]),
+            masked=bool(args["masked"]),
+            masked_crash=bool(args["masked_crash"]),
+            dedup=bool(args["dedup"]),
+            vt=int(args["vt"]),
             batch=batch * shards if shards else batch,
             shards=shards,
         )
@@ -237,7 +219,6 @@ def shapes_from_trace(doc: dict, *,
 
 
 def load_shapes(path: str, *,
-                model: tuple = DEFAULT_MODEL,
                 diagnostics: list | None = None) -> list[WarmShape]:
     """Sniff ``path``: a shape manifest (``{"shapes": [...]}``) or a
     recorded telemetry trace (``{"traceEvents": [...]}``).  Shapes are
@@ -248,8 +229,7 @@ def load_shapes(path: str, *,
     if "shapes" in doc:
         return shapes_from_manifest(doc, diagnostics=diagnostics)
     if "traceEvents" in doc:
-        return shapes_from_trace(doc, model=model,
-                                 diagnostics=diagnostics)
+        return shapes_from_trace(doc, diagnostics=diagnostics)
     raise ValueError(
         f"{path}: neither a shape manifest ({{'shapes': [...]}}) nor "
         f"a telemetry trace ({{'traceEvents': [...]}})")

@@ -50,7 +50,7 @@ time apportioned by occupancy — tracing-gated), feeds the
 directly comparable against the prepass's *predicted* ``prune_ratio``
 (``predicted_prune_ratio`` / ``prune_ratio_delta`` ride the block and
 the ``search.telemetry`` span, which is what ``tools/trace_report.py``
-and ``tools/obs_guard.py`` read out of ``BENCH_trace_*.json``).
+reads out of a recorded trace).
 
 Knob: ``JEPSEN_TPU_TELEMETRY`` (default ON; ``0``/``off`` disables,
 the CLI's ``--no-telemetry``).  Off-mode kernels are the exact
@@ -385,8 +385,8 @@ def finalize_result(result: dict, acc: "SearchTelemetry | None", *,
     """Close out one search's telemetry: compute the block, attach it
     to the result (``attach=True``), bump the ``jtpu_search_*``
     registry, and emit the ``search.telemetry`` span (tracing-gated)
-    so traces are self-contained — ``tools/trace_report.py`` and
-    ``obs_guard`` read predicted-vs-observed from the span args."""
+    so traces are self-contained — ``tools/trace_report.py`` reads
+    predicted-vs-observed from the span args."""
     if acc is None:
         return result
     predicted = _predicted_ratio(result, hbres)

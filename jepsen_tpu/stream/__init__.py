@@ -21,10 +21,6 @@ arXiv:1504.00204) into an *online* checker:
     service multiplexing history JSONL from many concurrent runs over
     stdin or a socket, all sharing one verdict cache: the fleet only
     ever pays for novel segments.
-  * :mod:`bench` — the streaming bench tier (``python bench.py
-    --stream-tier``): time-to-first-verdict, violation-detection
-    latency, sustained multiplexed ingest, written to
-    BENCH_stream.json.
 
 Wiring: ``core.prepare_test`` installs the sink next to the
 StreamLinter behind ``JEPSEN_TPU_STREAM=1`` / CLI ``--stream``;

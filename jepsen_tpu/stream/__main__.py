@@ -85,8 +85,8 @@ def main(argv=None) -> int:
     p.add_argument("--warmup", metavar="MANIFEST", default=None,
                    help="Warm-boot the steady-state kernels before "
                         "serving (fleet/warmup.py): MANIFEST is a "
-                        "shape-manifest JSON or a recorded "
-                        "BENCH_trace_*.json; prints a 'stream service "
+                        "shape-manifest JSON or a trace recorded "
+                        "with tracing on; prints a 'stream service "
                         "warmup:' line to stderr the fleet admission "
                         "gate parses.")
     args = p.parse_args(argv)

@@ -15,6 +15,10 @@ its completion event, which is a legal linearization point.  Knobs:
   * ``corrupt_at`` — fraction; rewrites one ok read near that point of the
     history to a bogus value, which (almost always) makes the history
     non-linearizable so the checker must sweep the full state space.
+
+:func:`baseline_history` and :func:`baseline_batch` rebuild the seeded
+histories of BASELINE.md's configurations (``chip_smoke.py`` and
+``tools/fusioncount.py`` run them).
 """
 
 from __future__ import annotations
@@ -407,3 +411,147 @@ def corrupt_dequeue(rng: random.Random, h: list[Op]) -> list[Op]:
     h = list(h)
     h[i] = replace(h[i], value=999_983)
     return h
+
+
+# ---------------------------------------------------------------------------
+# BASELINE.md's seeded histories
+# ---------------------------------------------------------------------------
+
+#: name -> (encoded op count, processes) of BASELINE.md's single
+#: histories; the seed is the name, so every process rebuilds the
+#: identical history
+BASELINE_HISTORIES = {
+    "1k": (1_000, 32),
+    "mutex2k": (2_000, 16),
+    "10k": (10_000, 32),
+    # every write a distinct value, overlap never drained: the class
+    # the per-value block decomposition is exact on
+    "10kuniq": (10_000, 32),
+    # 64 processes at overlap 32: wide pruned levels
+    "10k64": (10_000, 64),
+}
+
+#: BASELINE config 3's per-key history: (invoked ops, processes)
+BASELINE_BATCH_KEY = (128, 8)
+
+
+def _exact_encoded(gen, encode, target: int, *, lo_guess: int):
+    """Scan the generator's nominal invoke count until the ENCODED row
+    count equals ``target`` (encode_ops drops :fail ops, so the invoke
+    count overshoots it).  ``gen(n)`` -> event history; ``encode(h)``
+    -> OpSeq.  The scan order is fixed, so the result is deterministic;
+    when no candidate hits ``target`` exactly the nearest miss is
+    returned."""
+    n = lo_guess
+    best = None  # (abs gap, seq)
+    seen: set[int] = set()
+    for _ in range(200):
+        seq = encode(gen(n))
+        got = len(seq)
+        if got == target:
+            return seq
+        if best is None or abs(got - target) < best[0]:
+            best = (abs(got - target), seq)
+        seen.add(n)
+        # proportional step toward the target, at least +-1
+        step = int(round(n * (target - got) / max(1, got)))
+        n += step if step else (1 if got < target else -1)
+        n = max(target // 2, n)
+        if n in seen:
+            # walk to the nearest unvisited candidate; give up once the
+            # local neighborhood is exhausted
+            for d in range(1, 50):
+                if n + d not in seen:
+                    n += d
+                    break
+                if n - d > target // 2 and n - d not in seen:
+                    n -= d
+                    break
+            else:
+                break
+    return best[1]
+
+
+def baseline_history(name: str):
+    """``(seq, model)``: BASELINE.md's seeded history ``name`` (a key
+    of :data:`BASELINE_HISTORIES`), encoded to its nominal op count
+    (``mutex2k`` and ``10k64`` land within a few ops of it).  The
+    ``10k64`` scan takes tens of seconds on a CPU core."""
+    from .history import encode_ops
+    from .models import cas_register, mutex, register
+
+    n_ops, n_procs = BASELINE_HISTORIES[name]
+    if name == "10kuniq":
+        # no crashes and no cas, so the encoded count equals the invoke
+        # count; swapping two distant reads' values makes the history
+        # (almost surely) invalid through the forced block order
+        model = register(0)
+
+        def gen(n):
+            rng = random.Random(f"bench-{name}")
+            h = register_history(rng, n_ops=n, n_procs=n_procs,
+                                 overlap=8, crash_p=0.0, cas=False,
+                                 unique_writes=True)
+            return swap_read_values(rng, h)
+
+        lo_guess = n_ops
+    elif name == "mutex2k":
+        # BASELINE config 4: a lock with crashed (:info) ops.  The
+        # appended chain of (#info + 2) ok acquires makes the history
+        # invalid however the :info releases are placed, so the check
+        # must sweep rather than stop at a greedy witness
+        model = mutex()
+
+        def gen(n):
+            rng = random.Random(f"bench-{name}")
+            h = sim_mutex_history(rng, n_ops=n, n_procs=n_procs,
+                                  crash_p=0.01, max_crashes=12)
+            n_info = sum(1 for op in h if op.type == "info")
+            for i in range(n_info + 2):
+                p = n_procs + i
+                h = h + [invoke_op(p, "acquire", None),
+                         ok_op(p, "acquire", None)]
+            return h
+
+        lo_guess = n_ops
+    else:
+        model = cas_register()
+        overlap = 32 if name == "10k64" else 8
+
+        def gen(n):
+            rng = random.Random(f"bench-{name}")
+            h = register_history(rng, n_ops=n, n_procs=n_procs,
+                                 overlap=overlap, crash_p=0.002,
+                                 max_crashes=8, n_values=4)
+            return corrupt_read(rng, h, at=0.98)
+
+        lo_guess = int(n_ops * 1.35)
+    seq = _exact_encoded(gen, lambda h: encode_ops(h, model.f_codes),
+                         n_ops, lo_guess=lo_guess)
+    return seq, model
+
+
+def baseline_batch_key(k: int):
+    """``(seq, model)``: BASELINE config 3's key ``k``, a 128-op
+    8-process CAS-register history; every fourth key (``k % 4 == 0``)
+    has a corrupted read."""
+    from .history import encode_ops
+    from .models import cas_register
+
+    n_ops, n_procs = BASELINE_BATCH_KEY
+    model = cas_register()
+    rng = random.Random(f"bench-batch-{k}")
+    h = register_history(rng, n_ops=n_ops, n_procs=n_procs, overlap=4,
+                         crash_p=0.01, max_crashes=2, n_values=4)
+    if k % 4 == 0:
+        h = corrupt_read(rng, h, at=0.85)
+    return encode_ops(h, model.f_codes), model
+
+
+def baseline_batch(n_keys: int = 256):
+    """``(seqs, model)``: the first ``n_keys`` keys of BASELINE config
+    3 (:func:`baseline_batch_key`)."""
+    from .models import cas_register
+
+    return [baseline_batch_key(k)[0] for k in range(n_keys)], \
+        cas_register()

@@ -36,7 +36,7 @@ def enable_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its path.
 
     One rule for every entry point (the suite CLI, the stream workers,
-    bench.py, chip_smoke.py): where ``JAX_COMPILATION_CACHE_DIR`` is
+    chip_smoke.py): where ``JAX_COMPILATION_CACHE_DIR`` is
     set, JAX reads it itself and this sets no other path; otherwise the
     cache lives at the fixed ``<repo>/.jax_cache`` (a cache directory
     that moves between runs never hits).  Call it before the first

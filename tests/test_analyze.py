@@ -439,16 +439,14 @@ def test_differential_fuzz_wgl_and_batch():
 
 
 # ---------------------------------------------------------------------------
-# plan explainer vs the live engines (BENCH configs)
+# plan explainer vs the live engines (BASELINE configs)
 # ---------------------------------------------------------------------------
 
 
 def test_explain_matches_engine_facts_bench_batch_key():
-    """BENCH config #3's key shape: the plan's window/concurrency/dims
+    """BASELINE config 3's key shape: the plan's window/concurrency/dims
     must equal what the device engine reports after actually running."""
-    import bench
-
-    seq, model = bench.make_batch_key(0)  # valid key (k%4 != 0 pattern)
+    seq, model = synth.baseline_batch_key(0)
     plan = explain(seq, model)
     r = search_opseq(seq, model, budget=500_000)
     assert r["valid"] in (True, False)
@@ -496,12 +494,7 @@ def test_explain_engine_route_greedy_and_fallback():
 def test_explain_batch_matches_bucketed_run_stats():
     """The bucket plan (count + per-bucket dims + greedy/hard split)
     must equal the bucket_batch stats the live scheduler records."""
-    import bench
-
-    seqs, model = [], None
-    for k in range(12):
-        s, model = bench.make_batch_key(k)
-        seqs.append(s)
+    seqs, model = synth.baseline_batch(12)
     # one wide outlier so bucketing has real work to do (kept modest:
     # this test is about PLAN equality, not search throughput)
     rng = random.Random(77)

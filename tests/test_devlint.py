@@ -73,19 +73,30 @@ def test_thread_tier_is_clean():
         f"  {d.message}" for _f, d in errs)
 
 
-def test_committed_traces_satisfy_k007():
-    """Every committed BENCH_trace_*.json compile span carries a
-    documented cache-key coordinate generation."""
-    import glob
+#: route -> the span_kind its compile span must classify as
+_ROUTE_SPAN_KIND = {"solo-xla": "solo", "solo-pallas": "solo",
+                    "batch": "batch", "batch-sharded": "batch-sharded",
+                    "window-sharded": "window-sharded"}
 
-    paths = sorted(glob.glob(os.path.join(REPO, "BENCH_trace_*.json")))
-    assert paths, "no committed bench traces found"
-    for p in paths:
-        with open(p) as f:
-            doc = json.load(f)
-        diags = lint_trace_spans(doc, name=os.path.basename(p))
-        assert diags == [], f"{p}:\n" + "\n".join(
-            f"  {d.message}" for d in diags)
+
+@pytest.mark.parametrize("route", sorted(_ROUTE_SPAN_KIND))
+def test_committed_traces_satisfy_k007(fresh_trace, route):
+    """A trace recorded just now on each kernel route holds a compile
+    span of the route's span_kind, and every compile span in it
+    carries exactly the newest cache-key coordinate set (K007)."""
+    p = fresh_trace(route)
+    with open(p) as f:
+        doc = json.load(f)
+    spans = [ev["args"] for ev in doc["traceEvents"]
+             if ev.get("name") == "device.compile"]
+    assert spans, "the route compiled nothing"
+    assert {span_kind_for_args(a) for a in spans} == {
+        _ROUTE_SPAN_KIND[route]}
+    if route == "solo-pallas":
+        assert {a["engine"] for a in spans} == {"pallas"}
+    diags = lint_trace_spans(doc, name=os.path.basename(p))
+    assert diags == [], f"{p}:\n" + "\n".join(
+        f"  {d.message}" for d in diags)
 
 
 def test_devlint_cli_exit_codes():
@@ -286,9 +297,13 @@ def test_k007_missing_coord_fails_strict():
 
 
 def test_k007_legacy_generation_needs_non_strict():
+    """A PR 15-generation span (engine + two dims) fails K007: there is
+    no non-strict mode left to accept it."""
     legacy = {"engine": "xla", "frontier": 8, "n_det_pad": 64}
-    assert check_span_args(legacy, strict=True)
-    assert check_span_args(legacy, strict=False) == []
+    fails = check_span_args(legacy)
+    assert fails and "missing coords" in fails[0]
+    doc = {"traceEvents": [{"name": "device.compile", "args": legacy}]}
+    assert codes(lint_trace_spans(doc)) == {"K007"}
 
 
 def test_k007_domain_violation_fails_even_with_full_keys():
@@ -332,6 +347,32 @@ def test_warmup_manifest_validates_against_static_model(tmp_path):
         {"n_det_pad": 64, "frontier": 8, "window": 17}]}))
     with pytest.raises(ValueError, match="window"):
         load_shapes(str(man))
+
+
+@pytest.mark.parametrize("route", ["batch", "batch-sharded", "solo-xla",
+                                   "window-sharded"])
+def test_warm_boot_from_recorded_trace(fresh_trace, cold_kernel_cache,
+                                      route):
+    """Warm boot from a trace recorded just now: the loader takes every
+    compile span strictly, a boot from an empty kernel cache compiles
+    each shape once and verifies, and a second boot compiles nothing."""
+    from jepsen_tpu.fleet.warmup import load_shapes, warm_boot
+
+    shapes = load_shapes(fresh_trace(route))
+    assert shapes
+    if route.endswith("sharded"):
+        assert all(s.shards >= 2 for s in shapes)
+    if route.startswith("batch"):
+        assert all(s.batch >= 1 for s in shapes)
+    with cold_kernel_cache():
+        rep = warm_boot(shapes)
+        again = warm_boot(shapes)
+    assert "k007" not in rep
+    assert rep["verified"] is True
+    assert rep["shapes"] == len(shapes)
+    assert rep["compiled"] == len(shapes)
+    assert again["verified"] is True
+    assert again["compiled"] == 0
 
 
 def test_warm_boot_refuses_drifted_shapes():

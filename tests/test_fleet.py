@@ -218,13 +218,13 @@ def test_load_shapes_from_manifest_and_trace(tmp_path):
     assert len(shapes) == 1
     assert shapes[0].n_det_pad == 256 and shapes[0].window == 32
     trace = tmp_path / "trace.json"
+    span = {"engine": "xla", "n_det_pad": 1024, "frontier": 128,
+            "window": 64, "n_crash_pad": 32, "k": 4, "masked": False,
+            "masked_crash": False, "dedup": False, "vt": 8,
+            "model": "register", "model_init": 0, "model_width": 1}
     trace.write_text(json.dumps({"traceEvents": [
-        {"name": "device.compile", "args": {
-            "n_det_pad": 1024, "frontier": 128, "window": 64,
-            "n_crash_pad": 32, "k": 4}},
-        {"name": "device.compile", "args": {
-            "n_det_pad": 1024, "frontier": 128, "window": 64,
-            "n_crash_pad": 32, "k": 4}},  # duplicate span: dedup
+        {"name": "device.compile", "args": span},
+        {"name": "device.compile", "args": span},  # duplicate: dedup
         {"name": "device.slice", "args": {"frontier": 128}},
     ]}))
     shapes = load_shapes(str(trace))

@@ -220,8 +220,8 @@ def test_cross_backend_resume_keeps_pallas_evidence(tmp_path):
     """A TPU window runs pallas slices and checkpoints; the next window
     resumes on a host where pallas is off.  The accumulated verdict's
     engine label must still carry the pallas evidence (the checkpoint
-    persists the driver's actual-execution flag — through bench.py's
-    tmp-path + rename save pattern too)."""
+    persists the driver's actual-execution flag — through an atomic
+    tmp-path + rename save too)."""
     import os
     import time
 
@@ -238,9 +238,9 @@ def test_cross_backend_resume_keeps_pallas_evidence(tmp_path):
         saved = []
 
         def on_slice(carry, dims):
-            # bench.py's atomic save pattern: tmp path then rename
-            # (np.savez appends .npz when the suffix is missing, so
-            # the tmp name must keep it — same as bench.py's)
+            # atomic save: tmp path then rename (np.savez appends
+            # .npz when the suffix is missing, so the tmp name must
+            # keep it)
             lin.save_checkpoint(path + ".tmp.npz", carry, dims, model,
                                 10**7, seq=seq)
             os.replace(path + ".tmp.npz", path)

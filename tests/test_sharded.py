@@ -297,14 +297,44 @@ def test_bucketed_sharded_differential_fuzz(mesh, seed0):
     assert sb["hb_decided"] + sb["constraint_decided"] > 0
 
 
+#: per-bucket stats fields that must match `analyze.plan.explain_batch`
+#: field-for-field (the closed-loop cost-model contract)
+_EXPLAIN_BUCKET_FIELDS = ("searched", "dims", "lanes", "pad_lanes",
+                          "useful_ops", "padded_ops")
+_EXPLAIN_TOTAL_FIELDS = ("n_buckets", "greedy", "hb_decided",
+                         "constraint_decided", "hard", "useful_ops",
+                         "padded_ops", "fused_padded_ops")
+
+
+def _stats_match_plan(sb: dict, plan: dict) -> tuple[bool, list]:
+    """Field-for-field comparison of the live ``shard_batch`` stats
+    against ``explain_batch(..., n_devices=...)``'s prediction."""
+    diffs = []
+    for f in _EXPLAIN_TOTAL_FIELDS:
+        if sb.get(f) != plan.get(f):
+            diffs.append({"field": f, "live": sb.get(f),
+                          "plan": plan.get(f)})
+    live_b, plan_b = sb.get("buckets", []), plan.get("buckets", [])
+    if len(live_b) != len(plan_b):
+        diffs.append({"field": "len(buckets)", "live": len(live_b),
+                      "plan": len(plan_b)})
+    else:
+        for i, (lb, pb) in enumerate(zip(live_b, plan_b)):
+            for f in _EXPLAIN_BUCKET_FIELDS:
+                if lb.get(f) != pb.get(f):
+                    diffs.append({"field": f"buckets[{i}].{f}",
+                                  "live": lb.get(f),
+                                  "plan": pb.get(f)})
+    return not diffs, diffs
+
+
 def test_bucketed_sharded_explain_match(mesh):
     """explain_batch(n_devices=...)'s prediction matches the live
-    shard_batch stats field-for-field on bench-config keys — the
-    cost-model contract the shard tier gates on."""
+    shard_batch stats field-for-field on mixed-size keys — the
+    cost-model contract of the bucket planner."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     from jepsen_tpu.analyze.plan import explain_batch
-    from jepsen_tpu.checker.shard_bench import _stats_match_plan
 
     model = cas_register()
     seqs = []

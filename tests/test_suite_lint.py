@@ -680,6 +680,28 @@ def test_package_metrics_contract_holds():
         assert name in reg, name
 
 
+def test_benchmark_readers_name_registered_series():
+    """O001 over the benchmark's readers: every ``jtpu_*`` series a
+    file under ``benchmark/`` names is registered in the package, and
+    each such file is one of the default consumer surfaces."""
+    import re
+    from pathlib import Path
+
+    from jepsen_tpu.analyze.suites import METRIC_CONSUMERS
+
+    named: dict = {}
+    for f in sorted((Path(REPO) / "benchmark").rglob("*.py")):
+        for m in re.finditer(r"\bjtpu_[a-z0-9_]+\b", f.read_text()):
+            named.setdefault(f, set()).add(m.group(0))
+    # level_us divides by the search-level counter
+    assert any("jtpu_search_levels_total" in v for v in named.values())
+    surfaces = [Path(REPO) / c for c in METRIC_CONSUMERS]
+    for f in named:
+        assert any(f == s or s in f.parents for s in surfaces), f
+    out = _all(lint_metrics(consumers=sorted(named)))
+    assert [str(d) for d in out if d.code == "O001"] == []
+
+
 def test_new_codes_registered_and_cli_runs(capsys):
     for code in ("N001", "N002", "N003", "O001", "O002"):
         assert code in SUITE_CODES

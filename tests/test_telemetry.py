@@ -314,7 +314,7 @@ def test_device_level_spans_under_tracing():
     # which opens a hair before the slice span object itself records
     assert lvl[0]["ts"] >= min(x["ts"] for x in slc) - 5000.0
     # the search.telemetry span carries the result block (minus the
-    # per-level rows) — traces are self-contained for obs_guard
+    # per-level rows) — traces are self-contained for trace_report
     assert ts[-1]["args"]["observed_prune_ratio"] == \
         st["observed_prune_ratio"]
 

@@ -123,10 +123,10 @@ def main():
 
     import jax.numpy as jnp
 
-    import bench
+    from jepsen_tpu import synth
     from jepsen_tpu.checker import linearizable as lin
 
-    seq, model = bench.make_seq(args.tier)
+    seq, model = synth.baseline_history(args.tier)
     es = lin.encode_search(seq)
     for f in (int(w) for w in args.widths.split(",")):
         dims = lin.choose_dims(es, model, frontier=f)

@@ -3731,7 +3731,8 @@ def _search_batch_sharded_fixed(seqs: list[OpSeq],
             r = {"valid": _STATUS[int(status[i])],
                  "configs": int(configs[i]),
                  "max_depth": int(depth[i]),
-                 "engine": "device-batch"}
+                 "engine": "device-batch",
+                 "window": int(ess[i].window)}
             _device_batch_certificate(r)
             out.append(r)
     # which device each real key's lane was placed on, read off the
@@ -3789,6 +3790,10 @@ def _search_batch_ladder(seqs: list[OpSeq], esps: list[EncodedSearch],
     dispatch/compile, which is exactly what hurts on a real
     accelerator).  Keys still overflowing past the rung cap fall back
     to the solo adaptive ladder.
+
+    A batch verdict carries its key's own ``window`` (the padded
+    encodings keep it), so an invalid one can be cut by
+    `truncate_to_failure` as a solo search's is.
     """
     n = len(seqs)
     status = np.full(n, UNKNOWN, np.int32)
@@ -3852,7 +3857,8 @@ def _search_batch_ladder(seqs: list[OpSeq], esps: list[EncodedSearch],
             # UNKNOWN stands, with the true cumulative count.
             out.append({"valid": "unknown", "configs": int(spent[i]),
                         "max_depth": int(depth[i]),
-                        "engine": batch_engine})
+                        "engine": batch_engine,
+                        "window": int(esps[i].window)})
         elif needs_solo:
             # overflowed every shared rung: redo solo with the adaptive
             # ladder, on the REMAINING budget, reporting cumulative
@@ -3867,7 +3873,8 @@ def _search_batch_ladder(seqs: list[OpSeq], esps: list[EncodedSearch],
                 {"valid": _STATUS[int(status[i])],
                  "configs": int(configs[i]),
                  "max_depth": int(depth[i]),
-                 "engine": batch_engine}))
+                 "engine": batch_engine,
+                 "window": int(esps[i].window)}))
     if acc is not None and out:
         # batch-aggregate telemetry rides the FIRST result only (the
         # bucket_batch / decompose_batch convention: one shared stats
@@ -4145,23 +4152,7 @@ class Linearizable:
         model = self.model or test.get("model")
         if model is None:
             raise ValueError("linearizable checker needs a model")
-        from ..analyze.lint import (check_history, check_opseq_lint,
-                                    lint_enabled)
-
-        lint_warnings: list = []
-        do_lint = self.lint if self.lint is not None else lint_enabled()
-        if do_lint:
-            # event-level lint sees defects encoding erases (double
-            # invokes, orphan completions, type drift); an OpSeq input
-            # gets the columnar checks.  Errors raise HERE — before
-            # encode_ops can silently mis-pair the malformed events —
-            # and check_safe turns that into an "unknown" verdict
-            # carrying the diagnostic, never a wrong True/False.
-            with obs.span("linearizable.lint", rows=len(history)):
-                if isinstance(history, OpSeq):
-                    lint_warnings = check_opseq_lint(history, model)
-                else:
-                    lint_warnings = check_history(history, model)
+        lint_warnings = self._lint(history, model)
         if isinstance(history, OpSeq):
             seq = history
         else:
@@ -4182,7 +4173,31 @@ class Linearizable:
                 out["lint_warnings"] = [d.to_dict()
                                         for d in lint_warnings]
             return out
-        out = self._checked(test, seq, model, opts)
+        return self._finish(seq, model, self._checked(test, seq, model,
+                                                      opts),
+                            lint_warnings)
+
+    def _lint(self, history, model) -> list:
+        """The lint warnings of ``history``, or none where lint is off.
+        Event-level lint sees defects encoding erases (double invokes,
+        orphan completions, type drift); an OpSeq input gets the
+        columnar checks.  Errors raise HERE (HistoryLintError), before
+        encode_ops can silently mis-pair the malformed events, and
+        check_safe turns that into an "unknown" verdict carrying the
+        diagnostic, never a wrong True/False."""
+        from ..analyze.lint import (check_history, check_opseq_lint,
+                                    lint_enabled)
+
+        if not (self.lint if self.lint is not None else lint_enabled()):
+            return []
+        with obs.span("linearizable.lint", rows=len(history)):
+            if isinstance(history, OpSeq):
+                return check_opseq_lint(history, model)
+            return check_history(history, model)
+
+    def _finish(self, seq, model, out, lint_warnings):
+        """``out`` with ``lint_warnings`` attached, its certificate
+        replayed on ``seq`` where audit is on (`maybe_audit`)."""
         if lint_warnings and isinstance(out, dict):
             out.setdefault("lint_warnings",
                            [d.to_dict() for d in lint_warnings])
@@ -4191,6 +4206,39 @@ class Linearizable:
 
             maybe_audit(seq, model, out, self.audit)
         return out
+
+    def confirms_batch_verdict(self, out) -> bool:
+        """Whether `confirm_batch_verdict` stands in for this checker's
+        check of a key whose batch verdict is ``out``: where ``out`` is
+        a device search's, which carries the key's ``window`` (the
+        prepass's and the host engines' verdicts do not), and the check
+        would search the device and confirm on the host.  The host
+        engines and the decomposition run no such search, and
+        ``explain`` runs none at all."""
+        return ("window" in out
+                and self.algorithm in ("device", "auto", "competition")
+                and not self.decompose and not self.explain)
+
+    def confirm_batch_verdict(self, test, history, seq, out):
+        """``history``'s check from the invalid verdict ``out`` that a
+        device batch gave its encoding ``seq``, with no device search of
+        its own: `check`'s lint, then `_confirm_invalid` at the batch's
+        ``max_depth`` and ``window``.  Returns ``(result, None)``, or
+        ``(None, why)`` where the host did not confirm ``out`` and the
+        history needs `check`."""
+        from ..analyze.lint import HistoryLintError
+
+        model = self.model or test.get("model")
+        try:
+            lint_warnings = self._lint(history, model)
+        except HistoryLintError:
+            return None, "lint"
+        host = self._confirm_invalid(test, seq, model, out, {})
+        if host is None:
+            return None, "witness_threshold"
+        if host["valid"] is not False:
+            return None, f"prefix {host['valid']}"
+        return self._finish(seq, model, host, lint_warnings), None
 
     def _checked(self, test, seq, model, opts):
         if self.decompose:
@@ -4290,20 +4338,8 @@ class Linearizable:
                 # re-confirming would repeat the same search
                 self._render_failure(test, seq, out, opts, model)
                 return out
-            # exact confirmation + witness for the report, on the
-            # shortest sound prefix covering the failure region
-            with obs.span("linearizable.confirm") as sp:
-                target = seq
-                trunc = truncate_to_failure(seq, out.get("max_depth", 0),
-                                            out.get("window", 1))
-                if trunc is not None:
-                    target = trunc
-                confirm = self._host_recheck(target, model, out, sp)
-                sp.set(prefix_ops=len(target))
+            confirm = self._confirm_invalid(test, seq, model, out, opts)
             if confirm is not None and confirm["valid"] is False:
-                confirm["device_configs"] = out["configs"]
-                confirm["witness_prefix_ops"] = len(target)
-                self._render_failure(test, target, confirm, opts, model)
                 return confirm
             # no confirmation, or the prefix came back valid: the full
             # device verdict stands (obstruction lies past the cut)
@@ -4317,6 +4353,27 @@ class Linearizable:
     #: verdict's latency; after a device win of the competition, the
     #: host DFS that lost the race runs again for at most as long
     CERTIFY_TIME_SHARE = 1.0
+
+    def _confirm_invalid(self, test, seq, model, out, opts):
+        """The invalid device verdict ``out`` on ``seq``, confirmed by
+        the exact host search on the shortest sound prefix covering the
+        failure region (`truncate_to_failure` at ``out``'s
+        ``max_depth`` and ``window``).  Returns the host's result, with
+        a confirmed "invalid" rendered for the report, or None past
+        ``witness_threshold`` rows."""
+        with obs.span("linearizable.confirm") as sp:
+            target = seq
+            trunc = truncate_to_failure(seq, out.get("max_depth", 0),
+                                        out.get("window", 1))
+            if trunc is not None:
+                target = trunc
+            host = self._host_recheck(target, model, out, sp)
+            sp.set(prefix_ops=len(target))
+        if host is not None and host["valid"] is False:
+            host["device_configs"] = out["configs"]
+            host["witness_prefix_ops"] = len(target)
+            self._render_failure(test, target, host, opts, model)
+        return host
 
     def _host_recheck(self, seq, model, out, sp, **bounds):
         """The exact host search (`seq.check_opseq`) over ``seq`` behind

@@ -269,10 +269,30 @@ class IndependentChecker(Checker):
             seqs = [encode_ops(subhistories[k], model.f_codes)
                     for k in keys]
 
-        def one(i, why):
+        def one(i, why, **args):
             with obs.span("independent.key", key=keys[i],
-                          rows=len(seqs[i]), why=why):
+                          rows=len(seqs[i]), why=why, **args):
                 return check_safe(chk, test, subhistories[keys[i]])
+
+        def recheck(i, r):
+            """Key ``i``'s check, the batch having found it invalid
+            (``r``).  Where the sub-checker's own check would search the
+            device and confirm an invalid verdict on the host, the host
+            confirms ``r`` instead, at the batch's depth and window; a
+            key it does not confirm gets the sub-checker's own check."""
+            if not chk.confirms_batch_verdict(r):
+                return one(i, "recheck")
+            with obs.span("independent.key", key=keys[i],
+                          rows=len(seqs[i]), why="confirm") as sp:
+                try:
+                    out, why = chk.confirm_batch_verdict(
+                        test, subhistories[keys[i]], seqs[i], r)
+                except Exception as e:  # noqa: BLE001 — the own check
+                    # below meets it again, and check_safe reports it
+                    out, why = None, type(e).__name__
+                sp.set(confirmed=out is not None)
+            return out if out is not None else \
+                one(i, "recheck", fallback=why)
 
         # tiny histories aren't worth a device roundtrip; knossos-style
         # host checks for them, batch the rest
@@ -287,8 +307,7 @@ class IndependentChecker(Checker):
                                  budget=chk.budget)
             for i, r in zip(big, batch):
                 if r["valid"] is False:
-                    # exact host confirmation + witness, as in the solo path
-                    results[keys[i]] = one(i, "recheck")
+                    results[keys[i]] = recheck(i, r)
                 else:
                     results[keys[i]] = r
         return results

@@ -14,6 +14,8 @@ import queue
 import random
 import threading
 
+import pytest
+
 from jepsen_tpu.checker import linearizable as lin
 from jepsen_tpu.checker.bucket import (bucket_key, bucketing_enabled,
                                        plan_buckets,
@@ -99,6 +101,31 @@ def test_differential_bucketed_vs_unbucketed_reordered():
     buck = lin.search_batch(shuffled, m, budget=300_000, bucket=True)
     assert [buck[perm.index(i)]["valid"] for i in range(len(seqs))] == \
         [r["valid"] for r in base]
+
+
+@pytest.mark.parametrize("route", ["bucketed", "fused", "sharded"])
+def test_invalid_batch_verdict_carries_its_window(route):
+    """Each invalid verdict of the device carries its key's own window,
+    not the bucket's padded one, so `truncate_to_failure` cuts it where
+    it cuts a solo search's."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    seqs, m = _mixed_batch()
+    kw = {"bucket": route != "fused"}
+    if route == "sharded":
+        kw["sharding"] = NamedSharding(Mesh(np.array(jax.devices()),
+                                            ("shard",)),
+                                       PartitionSpec("shard"))
+    out = lin.search_batch(seqs, m, budget=300_000, **kw)
+    device = [(s, r) for s, r in zip(seqs, out)
+              if r["valid"] is False and r["engine"].startswith("device")]
+    assert len(device) >= 2
+    for s, r in device:
+        assert r["window"] == lin.encode_search(s).window
+    # the padded widths differ from the keys' own
+    assert {r["window"] for _s, r in device} - {32, 64}
 
 
 def test_differential_fuzz_random_batches():

@@ -805,7 +805,9 @@ def test_independent_checker_records_split_encode_key(tracing, tmp_path):
     assert plan["ts"] + plan["dur"] <= min(s["ts"]
                                            for s in by["bucket.prep"])
     keys = {s["args"]["key"]: s["args"] for s in by["independent.key"]}
-    assert keys[0]["why"] == "recheck" and keys[2]["why"] == "small"
+    # the batch-invalid key is confirmed on the host, with no solo search
+    assert keys[0]["why"] == "confirm" and keys[2]["why"] == "small"
+    assert keys[0]["confirmed"] is True
     assert 1 not in keys
     assert keys[0]["rows"] == 63  # encoded ops
     # the prep thread's spans belong to the same check

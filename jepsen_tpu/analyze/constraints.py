@@ -72,7 +72,7 @@ from collections import Counter
 
 import numpy as np
 
-from ..history import NIL, OpSeq
+from ..history import INF_RET, NIL, OpSeq
 from ..obs.metrics import REGISTRY
 from .hb import (
     EDGE_CAP_FACTOR,
@@ -160,7 +160,7 @@ def analyze_constraints(seq: OpSeq, model) -> HBAnalysis:
     n = len(seq)
     stats = {"solver": "constraints", "family": fam, "applies": False,
              "decided": None, "reason": None,
-             "edges": {"rf": 0, "fifo": 0}, "must_edges": 0}
+             "edges": {"rf": 0, "fifo": 0, "canon": 0}, "must_edges": 0}
     out = HBAnalysis(n=n, applies=False, decided=None, stats=stats)
     if fam is None:
         stats["reason"] = f"model {getattr(model, 'name', None)!r} " \
@@ -362,6 +362,10 @@ def _analyze_queue(seq: OpSeq, model, out: HBAnalysis,
                     break
             if best is None or ret[e] < best[0]:
                 best = (ret[e], d)
+    if not fifo and len(edges) < cap and n_enq < model.state_width \
+            and not any(q.deq_info for q in vals.values()):
+        edges += _queue_canon_edges(inv, ret, ok, fl, v1, stats,
+                                    cap - len(edges))
     for (_s, _d, k) in edges:
         stats["edges"][k] += 1
     stats["must_edges"] = len(edges)
@@ -370,6 +374,78 @@ def _analyze_queue(seq: OpSeq, model, out: HBAnalysis,
         must.setdefault(int(dst), []).append(int(src))
     out.must_pred = {d: tuple(sorted(set(s))) for d, s in must.items()}
     _prune_stats(seq, edges, stats)
+    return out
+
+
+def _queue_canon_edges(inv, ret, ok, fl, v1, stats: dict,
+                       cap: int) -> list[tuple[int, int, str]]:
+    """Canonical-order edges of an unordered queue whose capacity never
+    binds (fewer enqueues than lanes) and whose crashed dequeues carry
+    no value.  Two kinds, counted in ``stats`` (``early_enqueues``,
+    ``drain_chain``):
+
+      * a crashed enqueue goes before every :ok row invoked after it.
+        An element added earlier, or added at all, makes no op of a
+        multiset illegal, and the enqueue is then still after every row
+        that returned before its invocation — so any linearization
+        becomes one that satisfies these edges.  A crashed enqueue
+        then never stays open across levels, where each doubled the
+        frontier for as long as it might still come;
+      * a final drain, expanded into concurrent dequeues, goes in row
+        order: the largest group of :ok dequeues invoked last, pairwise
+        concurrent, after every other :ok row returned and before any
+        crashed row could be invoked.  In a linearization the crashed
+        rows among the group move before it (the enqueues by the first
+        kind already are; a dequeue without a value is a no-op), and
+        then the group's dequeues, each taking a value present, can
+        take any order — so they need only one, and the C(k, j)
+        subsets a search opens over k of them become one chain.
+
+    Edges that real time already implies are not emitted."""
+    from ..models import Q_DEQ, Q_ENQ
+
+    n = len(inv)
+    oks = sorted((i for i in range(n) if ok[i]), key=lambda i: inv[i])
+    if not oks:
+        return []
+    invs = [inv[i] for i in oks]
+    sfx = [0] * len(oks)        # least return from each :ok row on
+    m = INF_RET
+    for k in range(len(oks) - 1, -1, -1):
+        m = min(m, ret[oks[k]])
+        sfx[k] = m
+    out: list[tuple[int, int, str]] = []
+    crashed = [i for i in range(n) if not ok[i]]
+    early = 0
+    for c in crashed:
+        if fl[c] != Q_ENQ or v1[c] == NIL:
+            continue
+        k = bisect.bisect_right(invs, inv[c])
+        if k < len(oks):
+            early += 1
+        bound = sfx[k] if k < len(oks) else -1
+        while k < len(oks) and invs[k] < bound and len(out) < cap:
+            out.append((c, oks[k], "canon"))
+            k += 1
+    # the drain: the least s whose :ok suffix oks[s:] qualifies
+    last_crash_inv = max((inv[c] for c in crashed), default=-1)
+    pre = [-1] * (len(oks) + 1)  # greatest return before each :ok row
+    for k, r in enumerate(oks):
+        pre[k + 1] = max(pre[k], ret[r])
+    start = None
+    for s in range(len(oks) - 1, -1, -1):
+        r = oks[s]
+        if fl[r] != Q_DEQ or v1[r] == NIL or invs[-1] >= sfx[s] \
+                or last_crash_inv >= sfx[s]:
+            break
+        if pre[s] < invs[s] and s < len(oks) - 1:
+            start = s
+    chain = sorted(oks[start:]) if start is not None else []
+    for a, b in zip(chain, chain[1:]):
+        if len(out) < cap:
+            out.append((a, b, "canon"))
+    stats["early_enqueues"] = early
+    stats["drain_chain"] = len(chain)
     return out
 
 
@@ -461,8 +537,10 @@ def maybe_constraints(seq: OpSeq, model) -> HBAnalysis:
 
     fam = family_of(model) or "none"
     with obs.span("constraints.prepass", cat="analyze", rows=len(seq),
-                  family=fam):
+                  family=fam) as sp:
         a = analyze_constraints(seq, model)
+        sp.set(**{k: a.stats[k] for k in ("early_enqueues", "drain_chain")
+                  if k in a.stats})
     if not a.applies:
         _M_PREPASS.inc(family=fam, outcome="skipped")
         return a

@@ -394,13 +394,35 @@ def pad_search(es: EncodedSearch, n_det_pad: int, n_crash_pad: int,
 # ---------------------------------------------------------------------------
 
 
+#: a configuration of more words than this is WIDE (a queue's
+#: capacity-lane state): the forms unrolled once per word (the
+#: all-pairs prune, the chained word hash) grow its compile time and
+#: work with the width, so wide rows take the sort prune and a hash in
+#: one vector pass.  Compiled for a v5e, the level step of a 1,027-word
+#: row takes 68.6 s at frontier 64 and 177.0 s at 512 with all-pairs,
+#: 62.3 s at 256 sorting with the chained hash, and 4.9-9.5 s at 64-256
+#: with both changes
+WIDE_WORDS = 64
+
+
 def _hash_words(words, seed):
     """Vector fnv/murmur-style mix of int32 config words -> uint32.
 
-    words: uint32 [..., w]; returns uint32 [...].
+    words: uint32 [..., w]; returns uint32 [...].  A WIDE row mixes
+    each word with an odd multiplier of its position and sums: one
+    vector pass instead of a chain of w steps (an ordering key only —
+    dominance is decided on full words, so a collision costs nothing).
     """
-    h = jnp.full(words.shape[:-1], np.uint32(seed), dtype=jnp.uint32)
     w = words.shape[-1]
+    if w > WIDE_WORDS:
+        mult = (np.uint32(seed) + np.arange(w, dtype=np.uint32)
+                * np.uint32(0x9E3779B9)) | np.uint32(1)
+        x = words * mult
+        x = (x ^ (x >> 15)) * np.uint32(0x2C1B3C6D)
+        h = (x ^ (x >> 12)).sum(axis=-1, dtype=jnp.uint32)
+        h = (h ^ (h >> 13)) * np.uint32(0x85EBCA6B)
+        return h ^ (h >> 16)
+    h = jnp.full(words.shape[:-1], np.uint32(seed), dtype=jnp.uint32)
     for i in range(w):
         h = (h ^ words[..., i]) * np.uint32(0x85EBCA6B)
         h = (h ^ (h >> 13)) * np.uint32(0xC2B2AE35)
@@ -708,11 +730,14 @@ _ALLPAIRS_ELEMS = int(os.environ.get("JEPSEN_TPU_ALLPAIRS_ELEMS",
                                      str(1 << 28)))
 
 
-def _use_allpairs(M: int, batch: int = 1) -> bool:
-    """Decide the prune implementation for an M-row site.  Called at
-    kernel BUILD time only (the builders hoist the result), so the
-    decision is always consistent with the cache key computed from the
-    same module state."""
+def _use_allpairs(M: int, batch: int, words: int) -> bool:
+    """Decide the prune implementation for an M-row site of ``words``
+    words a row.  Called at kernel BUILD time only (the builders hoist
+    the result), so the decision is always consistent with the cache
+    key computed from the same module state.  A WIDE row always sorts:
+    the all-pairs form unrolls one [M, M] compare per word."""
+    if words > WIDE_WORDS:
+        return False
     if _DOMINANCE_MODE == "allpairs":
         return batch * M * M <= _ALLPAIRS_ELEMS
     if _DOMINANCE_MODE == "sort":
@@ -821,8 +846,8 @@ def build_search_step_fn(model: ModelSpec, dims: SearchDims,
                                  dedup=dedup, telemetry=telemetry)
     # prune implementation per site, decided at BUILD time (consistent
     # with the cache keys, which carry _dominance_key())
-    ap_cl = _use_allpairs(2 * F, batch)
-    ap_det = _use_allpairs(S, batch)
+    ap_cl = _use_allpairs(2 * F, batch, dims.words)
+    ap_det = _use_allpairs(S, batch, dims.words)
 
     def step(det_f, det_v1, det_v2, det_inv, det_ret, sfx_min,
              crash_f, crash_v1, crash_v2, crash_inv, det_mpred,
@@ -1072,8 +1097,8 @@ def build_sharded_search_step_fn(model: ModelSpec, dims: SearchDims,
     # which is the TPU-narrow-rung win the mode exists for).  On a
     # virtual CPU mesh all D instances share one host's RAM, but the
     # budget guards TPU HBM — hosts never select all-pairs anyway.
-    ap_cl = _use_allpairs(F + D * C_CR)
-    ap_det = _use_allpairs(D * C_DET)
+    ap_cl = _use_allpairs(F + D * C_CR, 1, dims.words)
+    ap_det = _use_allpairs(D * C_DET, 1, dims.words)
 
     def route(cfgs, valid, cap: int):
         """all_to_all home-routing by pw-hash.  Returns the received
@@ -1741,7 +1766,7 @@ def search_opseq_sharded(seq: OpSeq, model: ModelSpec, mesh, *,
 
         carry = _drive_slices(call, carry0, is_active, on_slice=track,
                               deadline=deadline, stop=stop,
-                              describe=describe)
+                              describe=describe, state_words=dims.words)
         status = sc(carry, 2)
         configs = sc(carry, 3)
         ovf = bool(sc(carry, 5))
@@ -1862,7 +1887,7 @@ def _entry_depth(count, configs, depth):
 
 def _drive_slices(call, carry, is_active, *, on_slice=None,
                   deadline: float | None = None, stop=None,
-                  describe=None):
+                  describe=None, state_words: int):
     """Shared host loop for the batch and sharded kernels.  (The
     single-device path has its own driver inside ``_run_kernel``: it
     re-keys the kernel between slices as the frontier width adapts,
@@ -1876,13 +1901,15 @@ def _drive_slices(call, carry, is_active, *, on_slice=None,
     an "unknown" verdict in the callers.  The first slice's wall time
     includes trace+compile, so it never feeds cap adaptation.
     ``describe(before, after)``, with tracing on, gives the slice's
-    ``device.slice`` args read off its carries (`_slice_args`)."""
+    ``device.slice`` args read off its carries (`_slice_args`).
+    ``state_words`` is the words of one packed configuration, the
+    spans' arg of that name."""
     lvl_cap = _SLICE_LEVELS0
     first = True
     while True:
         t0 = time.perf_counter()
         with obs.span("device.slice", cat="device", levels=lvl_cap,
-                      first=first) as sp:
+                      first=first, state_words=state_words) as sp:
             before = carry
             carry = call(carry, lvl_cap)
             jax.block_until_ready(carry)
@@ -2252,7 +2279,8 @@ def _run_kernel(esp: EncodedSearch, es: EncodedSearch, model: ModelSpec,
         t0 = time.perf_counter()
         tele_buf = None
         with obs.span("device.slice", cat="device", frontier=F,
-                      levels=lvl_cap, first=first) as sp:
+                      levels=lvl_cap, first=first,
+                      state_words=dims.words) as sp:
             res = fn(*args, jnp.int32(budget), jnp.int32(lvl_cap),
                      jnp.bool_(bail), *carry)
             if tele_on:
@@ -2848,8 +2876,8 @@ def get_batch_kernel(model: ModelSpec, dims: SearchDims,
     S = 4 * F
     use_p = allow_pallas and _use_pallas(model, dims, masked=masked,
                                          dedup=dedup)
-    sel = (_use_allpairs(2 * F, batch),
-           _use_allpairs(S, batch),
+    sel = (_use_allpairs(2 * F, batch, dims.words),
+           _use_allpairs(S, batch, dims.words),
            _use_matrix_compact(F, F * K, batch),
            _use_matrix_compact(S, F * K, batch),
            _use_matrix_compact(F, 2 * F, batch),
@@ -2949,8 +2977,8 @@ def get_sharded_batch_kernel(model: ModelSpec, dims: SearchDims, *,
     S = 4 * F
     # the prune/compaction selections see the PER-SHARD lane count —
     # that is the batch the inner kernel is built at
-    sel = (_use_allpairs(2 * F, per),
-           _use_allpairs(S, per),
+    sel = (_use_allpairs(2 * F, per, dims.words),
+           _use_allpairs(S, per, dims.words),
            _use_matrix_compact(F, F * K, per),
            _use_matrix_compact(S, F * K, per),
            _use_matrix_compact(F, 2 * F, per),
@@ -3710,7 +3738,8 @@ def _search_batch_sharded_fixed(seqs: list[OpSeq],
         return np.asarray(
             multihost_utils.process_allgather(x, tiled=True))
 
-    carry = _drive_slices(call, carry, is_active, describe=describe)
+    carry = _drive_slices(call, carry, is_active, describe=describe,
+                          state_words=dims.words)
     status = gather(carry[2])
     count = gather(carry[1])
     configs = gather(carry[3])

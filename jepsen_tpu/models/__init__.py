@@ -27,6 +27,7 @@ per knossos.model semantics is always legal and does not change state.
 
 from __future__ import annotations
 
+import bisect
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Tuple
 
@@ -293,17 +294,18 @@ def _uq_pystep_factory(capacity: int):
             # nothing and changes nothing, matching the register models'
             # NIL convention
             return state
+        # the lanes are sorted (empty lanes last): bisect, not a scan
+        # or a sort of the whole capacity
         if f == Q_ENQ:
             if state[capacity - 1] != Q_EMPTY:
                 return None  # over capacity: size the model to the history
-            s = sorted(state[:capacity - 1] + (v1,))
-            return tuple(s) + (Q_EMPTY,) * (capacity - len(s))
+            i = bisect.bisect_right(state, v1)
+            return state[:i] + (v1,) + state[i:capacity - 1]
         if f == Q_DEQ:
-            if v1 not in state:
+            i = bisect.bisect_left(state, v1)
+            if i == capacity or state[i] != v1:
                 return None
-            s = list(state)
-            s.remove(v1)
-            return tuple(s) + (Q_EMPTY,)
+            return state[:i] + state[i + 1:] + (Q_EMPTY,)
         raise ValueError(f"unordered-queue: bad f code {f}")
 
     return pystep

@@ -22,7 +22,7 @@ import jax
 
 from jepsen_tpu.checker import linearizable as lin
 from jepsen_tpu.checker import pallas_level
-from jepsen_tpu.models import cas_register, mutex
+from jepsen_tpu.models import cas_register, mutex, unordered_queue
 
 
 @pytest.fixture(scope="module")
@@ -87,6 +87,22 @@ def test_single_xla_kernel_at_1k_tier_size(one_chip, tpu_selectors):
     compiled = jax.jit(lin.build_search_step_fn(model, dims)).lower(
         *_specs(args, one_chip)).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_single_xla_kernel_at_rabbitmq_queue_size(one_chip, tpu_selectors):
+    # the rabbitmq-queue cell's dims: unordered_queue(1024) (a 1,027-word
+    # configuration), 1,800 ops, window 32, 12 crashes, the queue
+    # prepass's crash-pred mask; a wide row takes the sort prune
+    model = unordered_queue(1024)
+    dims = _dims(model, frontier=64, window=32, n_crash_pad=32,
+                 n_det_pad=2048, k=8)
+    assert dims.words == 1027
+    assert not lin._use_allpairs(4 * 64, 1, dims.words)
+    args = lin.route_sample_inputs(model, dims)
+    step = lin.build_search_step_fn(model, dims, masked=True,
+                                    masked_crash=True, telemetry=True)
+    compiled = jax.jit(step).lower(*_specs(args, one_chip)).compile()
+    assert compiled.memory_analysis() is not None
 
 
 @pytest.mark.parametrize("model_name,n_det_pad", [

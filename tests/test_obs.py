@@ -764,6 +764,49 @@ def test_device_check_records_phase_spans(tracing, tmp_path):
     assert {s["check"] for s in spans2} == {checks.pop() + 1}
 
 
+def _queue_events():
+    """A crashed enqueue whose value is dequeued (so completion order is
+    no linearization and the search runs), then a drain of two."""
+    return [invoke_op(0, "enqueue", 1), invoke_op(1, "enqueue", 2),
+            info_op(0, "enqueue", 1), ok_op(1, "enqueue", 2),
+            invoke_op(1, "enqueue", 3), ok_op(1, "enqueue", 3),
+            invoke_op(2, "dequeue", None), ok_op(2, "dequeue", 1),
+            invoke_op(3, "dequeue", None), invoke_op(4, "dequeue", None),
+            ok_op(3, "dequeue", 2), ok_op(4, "dequeue", 3)]
+
+
+@pytest.mark.parametrize("case", ["register", "queue"])
+def test_device_slice_counts_the_words_of_a_configuration(tracing, tmp_path,
+                                                          case):
+    """`state_words` on every `device.slice`: the words of one packed
+    configuration, its position, window words, crash words and model
+    state (1 for a register, the capacity for a queue)."""
+    from jepsen_tpu.checker.linearizable import Linearizable, encode_search
+    from jepsen_tpu.history import encode_ops
+    from jepsen_tpu.models import cas_register, unordered_queue
+
+    model, events = {"register": (cas_register(0), _stale_events(30)),
+                     "queue": (unordered_queue(64), _queue_events())}[case]
+    chk = Linearizable(model, algorithm="device")
+    r, spans = _checked_spans(chk, events, tmp_path)
+    assert r["valid"] is (case == "queue")
+    slices = [s for s in spans if s["name"] == "device.slice"]
+    assert slices
+    # no crash needs more than one word
+    window = encode_search(encode_ops(events, model.f_codes)).window
+    window_words = -(-window // 32)
+    for s in slices:
+        assert s["args"]["state_words"] == \
+            1 + window_words + 1 + model.state_width
+    if case == "queue":
+        assert slices[0]["args"]["state_words"] == 67
+        # the queue prepass's canonical edges: one crashed enqueue
+        # placed early, a drain of two chained
+        pre = next(s for s in spans if s["name"] == "constraints.prepass")
+        assert (pre["args"]["early_enqueues"],
+                pre["args"]["drain_chain"]) == (1, 2)
+
+
 def test_tracing_off_records_no_span(tmp_path):
     from jepsen_tpu.checker.linearizable import Linearizable
     from jepsen_tpu.models import cas_register
